@@ -10,6 +10,8 @@ The roofline analysis (deliverable g) is its own module: benchmarks.roofline.
 import argparse
 import sys
 
+from repro import cache as cache_mod
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -18,6 +20,7 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma list: fig2,table1,fig3,serve,kernels")
     args = ap.parse_args()
+    cache_mod.configure_compile_cache()
     which = set((args.only or "fig2,table1,fig3").split(","))
 
     print("name,us_per_call,derived")

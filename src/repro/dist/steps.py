@@ -27,7 +27,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.dist import compression
@@ -148,7 +148,7 @@ def make_train_step(model, cfg, opt, accum_steps: int = 1,
             local_fn, mesh=compress_mesh,
             in_specs=(rep, sharded, err_spec),
             out_specs=(P(), rep, err_spec),
-            check_rep=False,
+            check_vma=False,
         )(params, batch, error)
 
     def step(state, batch):

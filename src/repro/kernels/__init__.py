@@ -35,7 +35,7 @@ Layout (per repo convention):
   building block of every > ``MAX_FUSED_N`` regime.
 * ``autotune.py``           — first-call on-device row-block sweep
   ({64, 128, 256}, memoized per (N, K, dtype, direction) and persisted
-  to ``results/autotune_cache.json`` for device runs) feeding ``bm`` to
+  to ``.cache/autotune.json`` for device runs) feeding ``bm`` to
   the fused fwd/bwd/cascade/cascade_bwd kernels; returns the old fixed
   constants off-device so CPU/CI runs are unchanged.
 * ``ops.py``                — jit'd public wrappers + custom VJPs:

@@ -28,7 +28,7 @@ packed into the cache's int slot via ``paged_attn.encode_block``,
 filtered by the kernel's per-chunk budget, keyed on (head_dim, T)).
 
 Sweep winners also persist across processes: real device sweeps are
-spilled to ``results/autotune_cache.json`` (keyed by backend —
+spilled to ``.cache/autotune.json`` (``repro.cache``; keyed by backend —
 fallback constants never leak between backends) and reloaded lazily on
 the first TPU-side miss, so repeated ``launch/train`` runs skip the
 first-call on-device sweep.  ``REPRO_AUTOTUNE_CACHE=0`` disables the
@@ -54,6 +54,7 @@ from typing import Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import cache as cache_mod
 from repro.core import families as families_mod
 from repro.kernels import acdc_bwd as bwd_mod
 from repro.obs import metrics as obs_metrics
@@ -133,7 +134,7 @@ def _candidates(direction: str, n: int, k: int, *, bias: bool,
         return [paged_attn_mod.encode_block((pc, bh))
                 for pc in paged_attn_mod.PAGE_CHUNKS
                 for bh in paged_attn_mod.HEAD_BLOCKS
-                if _PAGED_SWEEP["hkv"] % bh == 0
+                if paged_attn_mod.legal_head_block(bh, _PAGED_SWEEP["hkv"])
                 and paged_attn_mod.paged_attn_vmem_bytes(
                     bs=_PAGED_SWEEP["bs"], dh=n,
                     group=_PAGED_SWEEP["group"], t=k, pc=pc, bh=bh,
@@ -142,7 +143,7 @@ def _candidates(direction: str, n: int, k: int, *, bias: bool,
 
 
 # ---------------------------------------------------------------------------
-# Persistent sweep cache (results/autotune_cache.json).
+# Persistent sweep cache (.cache/autotune.json, beside the compile cache).
 #
 # Sweeps are memoized per process; a fresh ``launch/train`` run used to
 # re-pay the first-call on-device sweep for every (N, K, dtype,
@@ -165,9 +166,7 @@ def _cache_path() -> str:
     override = os.environ.get(CACHE_ENV + "_PATH")
     if override:
         return override
-    root = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__)))))
-    return os.path.join(root, "results", "autotune_cache.json")
+    return str(cache_mod.AUTOTUNE_CACHE_PATH)
 
 
 def _key_str(key: Tuple) -> str:
@@ -376,15 +375,13 @@ def autotuned_bm(direction: str, n: int, k: int = 1, dtype=jnp.float32, *,
         hit = _CACHE.get(key)
         if hit is not None:
             return hit
-        try:
-            bm = sweep(direction, n, k, dtype, bias=bias, permute=permute,
-                       family=family)
-            _save_persistent(key, bm)
-            _SWEEPS.labels(direction=direction).inc()
-            obs_trace.instant_global("autotune", "sweep",
-                                     direction=direction,
-                                     key=_key_str(key), winner=int(bm))
-        except Exception:
-            bm = _fallback(direction, n, k, bias=bias, permute=permute)
+        # a candidate the compiler refuses is a bug in the kernel or its
+        # budget model: it raises here rather than hide behind a fallback
+        bm = sweep(direction, n, k, dtype, bias=bias, permute=permute,
+                   family=family)
+        _save_persistent(key, bm)
+        _SWEEPS.labels(direction=direction).inc()
+        obs_trace.instant_global("autotune", "sweep", direction=direction,
+                                 key=_key_str(key), winner=int(bm))
     _CACHE[key] = bm
     return bm

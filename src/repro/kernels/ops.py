@@ -64,7 +64,14 @@ from repro.kernels import paged_attn as paged_attn_mod
 from repro.kernels import scaled_matmul as smm_mod
 from repro.obs.metrics import REGISTRY, CounterDict
 
-_INTERPRET = jax.default_backend() != "tpu"
+
+def interpret_mode() -> bool:
+    """Whether the Pallas kernels run in interpret mode: everywhere but
+    on a TPU.  Asked at trace time by every kernel call site, so a test
+    can steer it (monkeypatch) and a process's answer follows the backend
+    it actually has rather than the one it had at import."""
+    return jax.default_backend() != "tpu"
+
 
 #: trace-time routing decisions of the cascade backward, for benches/CI:
 #: every time a cascade VJP backward is traced, exactly one bucket
@@ -108,7 +115,7 @@ def paged_attn_route(hkv: int, dh: int, group: int, t: int, bs: int,
     Every trace increments exactly one ``PAGED_ATTN_DISPATCHES`` bucket.
     """
     itemsize = jnp.dtype(dtype).itemsize
-    if not (paged_attn_mod.FORCE_FUSED or jax.default_backend() == "tpu"):
+    if interpret_mode() and not paged_attn_mod.FORCE_FUSED:
         PAGED_ATTN_DISPATCHES["gather"] += 1
         return None
     enc = autotune.autotuned_bm("paged_attn", dh, t, dtype)
@@ -171,7 +178,8 @@ def _acdc_bwd_impl(x2, a, d, g2, *, family="acdc", with_bias=True,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _fused_bias(family, x, a, d, bias):
     x2, shape = _flatten(x)
-    y = _acdc_fwd_impl(x2, a, d, bias, family=family, interpret=_INTERPRET)
+    y = _acdc_fwd_impl(x2, a, d, bias, family=family,
+                       interpret=interpret_mode())
     return y.reshape(shape)
 
 
@@ -184,7 +192,7 @@ def _fused_bias_bwd(family, res, g):
     x2, shape = _flatten(x)
     g2, _ = _flatten(g)
     dx2, da, dd, db = _acdc_bwd_impl(x2, a, d, g2, family=family,
-                                     interpret=_INTERPRET)
+                                     interpret=interpret_mode())
     return (dx2.reshape(shape), da.astype(a.dtype), dd.astype(d.dtype),
             db.astype(bias.dtype))
 
@@ -195,7 +203,8 @@ _fused_bias.defvjp(_fused_bias_fwd, _fused_bias_bwd)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _fused_nobias(family, x, a, d):
     x2, shape = _flatten(x)
-    y = _acdc_fwd_impl(x2, a, d, None, family=family, interpret=_INTERPRET)
+    y = _acdc_fwd_impl(x2, a, d, None, family=family,
+                       interpret=interpret_mode())
     return y.reshape(shape)
 
 
@@ -208,7 +217,8 @@ def _fused_nobias_bwd(family, res, g):
     x2, shape = _flatten(x)
     g2, _ = _flatten(g)
     dx2, da, dd, _ = _acdc_bwd_impl(x2, a, d, g2, family=family,
-                                    with_bias=False, interpret=_INTERPRET)
+                                    with_bias=False,
+                                    interpret=interpret_mode())
     return dx2.reshape(shape), da.astype(a.dtype), dd.astype(d.dtype)
 
 
@@ -291,7 +301,7 @@ def _cascade_bwd_fused(relu, permute, x, a, d, bias, g, family="acdc"):
     with jax.named_scope("acdc_cascade_bwd_reverse_sweep"):
         dx, da, dd, db = cascade_bwd_mod.acdc_cascade_bwd_pallas(
             x2, g2, a, d, bias, c, ct, ct_mid, relu=relu, bm=bm,
-            interpret=_INTERPRET)
+            interpret=interpret_mode())
     dx = dx.reshape(shape)
     if bias is None:
         return dx, da.astype(a.dtype), dd.astype(d.dtype)
@@ -325,7 +335,7 @@ def _cascade_bwd_core(relu, permute, x, a, d, bias, g, family="acdc"):
     n = x.shape[-1]
     x2, shape = _flatten(x)
     g2, _ = _flatten(g)
-    interp = _INTERPRET
+    interp = interpret_mode()
     perm = inv_perm = None
     if permute:
         p = families_mod.get_family(family).riffle(n)
@@ -393,7 +403,7 @@ def _cascade_bwd_core(relu, permute, x, a, d, bias, g, family="acdc"):
 def _cascade_bias(relu, permute, family, x, a, d, bias):
     x2, shape = _flatten(x)
     y = _cascade_fwd_impl(x2, a, d, bias, relu, permute, family,
-                          interpret=_INTERPRET)
+                          interpret=interpret_mode())
     return y.reshape(shape)
 
 
@@ -414,7 +424,7 @@ _cascade_bias.defvjp(_cascade_bias_fwd, _cascade_bias_bwd)
 def _cascade_nobias(relu, permute, family, x, a, d):
     x2, shape = _flatten(x)
     y = _cascade_fwd_impl(x2, a, d, None, relu, permute, family,
-                          interpret=_INTERPRET)
+                          interpret=interpret_mode())
     return y.reshape(shape)
 
 
@@ -499,5 +509,5 @@ def scaled_matmul(
     """Blocked scaled matmul on the last axis of ``x``."""
     x2, shape = _flatten(x)
     y = smm_mod.scaled_matmul_pallas(x2, w, pre, post, bias,
-                                     interpret=_INTERPRET)
+                                     interpret=interpret_mode())
     return y.reshape(*shape[:-1], w.shape[-1])

@@ -7,7 +7,7 @@ the bytes it needs.  This kernel (vLLM-style) never builds that view:
 
 * each grid program ``(slot, head-block)`` walks its slot's block table
   (scalar-prefetched into SMEM) and DMAs only the *mapped, in-frontier*
-  pages of K/V from the pool (``pltpu.ANY`` memory space) into a VMEM
+  pages of K/V from the pool (``pl.ANY`` memory space) into a VMEM
   chunk buffer, ``page_chunk`` pages per round;
 * attention runs as an online softmax (flash-style running max m and
   denominator l in fp32) per chunk, with the causal/window mask computed
@@ -63,8 +63,8 @@ from repro.kernels.acdc_cascade_fused import VMEM_BUDGET
 
 #: page-chunk candidates (pages DMA'd per streaming round), largest first
 PAGE_CHUNKS = (8, 4, 2, 1)
-#: KV-head row-block candidates, largest first (clamped to divisors of
-#: the model's Hkv at the call site)
+#: KV-head row-block candidates, largest first (a call site uses only
+#: those :func:`legal_head_block` accepts for its Hkv)
 HEAD_BLOCKS = (8, 4, 2, 1)
 #: deterministic off-device answer, pre-clamp
 DEFAULT_BLOCK = (4, 4)
@@ -102,13 +102,20 @@ def paged_attn_vmem_bytes(*, bs: int, dh: int, group: int, t: int,
     return stream + q + state + newkv + out
 
 
+def legal_head_block(bh: int, hkv: int) -> bool:
+    """Whether the TPU compiler accepts ``bh`` heads per program: the
+    new-token K/V blocks ``(1, T, bh, Dh)`` put ``bh`` on the sublane
+    axis, which Mosaic tiles by 8 unless the block spans all ``hkv``."""
+    return hkv % bh == 0 and (bh == hkv or bh % 8 == 0)
+
+
 def pick_block(*, hkv: int, dh: int, group: int, t: int, bs: int,
                itemsize: int) -> Optional[Tuple[int, int]]:
     """Largest in-budget (page_chunk, head_block), or None if nothing
     fits (the dispatcher then keeps the gather fallback)."""
     for pc in PAGE_CHUNKS:
         for bh in HEAD_BLOCKS:
-            if hkv % bh:
+            if not legal_head_block(bh, hkv):
                 continue
             if paged_attn_vmem_bytes(bs=bs, dh=dh, group=group, t=t,
                                      pc=pc, bh=bh,
@@ -119,16 +126,16 @@ def pick_block(*, hkv: int, dh: int, group: int, t: int, bs: int,
 
 def clamp_block(blk: Tuple[int, int], *, hkv: int, dh: int, group: int,
                 t: int, bs: int, itemsize: int) -> Optional[Tuple[int, int]]:
-    """Fit an autotuned/default (pc, bh) to this call site: bh must
-    divide Hkv and the pair must be in budget; degrade toward
-    :func:`pick_block`'s answer rather than fail."""
+    """Fit an autotuned/default (pc, bh) to this call site: bh must be a
+    :func:`legal_head_block` for Hkv and the pair must be in budget;
+    degrade toward :func:`pick_block`'s answer rather than fail."""
     pc, bh = blk
-    bh = min(bh, hkv)
-    while bh > 1 and hkv % bh:
-        bh -= 1
-    if paged_attn_vmem_bytes(bs=bs, dh=dh, group=group, t=t, pc=pc, bh=bh,
-                             itemsize=itemsize) <= VMEM_BUDGET:
-        return pc, bh
+    legal = [h for h in range(min(bh, hkv), 0, -1)
+             if legal_head_block(h, hkv)]
+    if legal and paged_attn_vmem_bytes(
+            bs=bs, dh=dh, group=group, t=t, pc=pc, bh=legal[0],
+            itemsize=itemsize) <= VMEM_BUDGET:
+        return pc, legal[0]
     return pick_block(hkv=hkv, dh=dh, group=group, t=t, bs=bs,
                       itemsize=itemsize)
 
@@ -137,9 +144,12 @@ def _kernel(virtual, t, bs, pc, bh, group, dh, softcap,
             routed_r, pos_r, start_r, nch_r, phys_r, off_r, win_r,
             q_ref, kn_ref, vn_ref, kp_hbm, vp_hbm,
             o_ref, kp_out, vp_out, kbuf, vbuf, sem_k, sem_v, sem_s):
+    # Mosaic lowers 2-D matmuls, reductions and masks; every per-head
+    # tile below is therefore 2-D: query rows ``r = tt * group + g``
+    # (tg of them) against one key per column.
     i = pl.program_id(0)
-    hb = pl.program_id(1)
-    h0 = hb * bh
+    h0 = pl.program_id(1) * bh
+    tg = t * group
 
     # -- 1. persist the T new tokens' K/V head-slice into their (already
     #    trash-routed) tail pages.  Disjoint from every streamed read
@@ -148,37 +158,46 @@ def _kernel(virtual, t, bs, pc, bh, group, dh, softcap,
         page = phys_r[i, tt]
         o = off_r[i, tt]
         ck = pltpu.make_async_copy(
-            kn_ref.at[tt], kp_out.at[page, o, pl.ds(h0, bh)], sem_s.at[0])
+            kn_ref.at[0, tt], kp_out.at[page, o, pl.ds(h0, bh)], sem_s.at[0])
         cv = pltpu.make_async_copy(
-            vn_ref.at[tt], vp_out.at[page, o, pl.ds(h0, bh)], sem_s.at[1])
+            vn_ref.at[0, tt], vp_out.at[page, o, pl.ds(h0, bh)], sem_s.at[1])
         ck.start()
         cv.start()
         ck.wait()
         cv.wait()
 
     # -- 2. online softmax over the streamed prefix + the new tokens.
-    q = q_ref[...].astype(jnp.float32)                 # (t, bh, group, dh)
     scale = dh ** -0.5
     pos_i = pos_r[i]
     win = win_r[0]
-    qp = pos_i + jax.lax.broadcasted_iota(jnp.int32, (t, 1), 0)  # (t, 1)
+    # the window as a distance bound; "global" is one no distance reaches
+    wlim = jnp.where(win > 0, win, jnp.int32(2 ** 30))
+    qs = [q_ref[0, hh].astype(jnp.float32) for hh in range(bh)]  # (tg, dh)
 
-    def fold(carry, kc, vc, msk):
-        """One chunk of keys into the running (m, l, acc) state.
-        kc/vc: (kk, bh, dh); msk: (t, kk), True = attend."""
-        m, l, acc = carry
-        s = jnp.einsum("thgd,khd->hgtk", q, kc.astype(jnp.float32),
-                       preferred_element_type=jnp.float32) * scale
+    def query_pos(shape):
+        """(tg, kk) position of each query row: pos + row // group."""
+        row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        qp = jnp.full(shape, pos_i, jnp.int32)
+        for tt in range(1, t):
+            qp = qp + jnp.where(row >= tt * group, 1, 0)
+        return qp
+
+    def fold(state, q, kc, vc, msk):
+        """One block of keys into a head's running (m, l, acc) state.
+        q: (tg, dh) fp32; kc/vc: (kk, dh); msk: (tg, kk), True = attend."""
+        m, l, acc = state
+        s = jax.lax.dot_general(
+            q, kc.astype(jnp.float32), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
         if softcap > 0:
             s = softcap * jnp.tanh(s / softcap)
-        s = jnp.where(msk[None, None], s, -1e30)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        s = jnp.where(msk, s, -1e30)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         corr = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new[..., None])
-        l_new = l * corr + jnp.sum(p, axis=-1)
-        acc_new = acc * corr[..., None] + jnp.einsum(
-            "hgtk,khd->hgtd", p, vc.astype(jnp.float32),
-            preferred_element_type=jnp.float32)
+        p = jnp.exp(s - m_new)
+        l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_new = acc * corr + jnp.dot(p, vc.astype(jnp.float32),
+                                       preferred_element_type=jnp.float32)
         return m_new, l_new, acc_new
 
     def chunk(ci, carry):
@@ -195,31 +214,33 @@ def _kernel(virtual, t, bs, pc, bh, group, dh, softcap,
                                   kbuf.at[jj], sem_k.at[jj]).wait()
             pltpu.make_async_copy(vp_hbm.at[page, :, pl.ds(h0, bh)],
                                   vbuf.at[jj], sem_v.at[jj]).wait()
-        kc = kbuf[...].reshape(pc * bs, bh, dh)
-        vc = vbuf[...].reshape(pc * bs, bh, dh)
-        kpos = base * bs + jax.lax.broadcasted_iota(
-            jnp.int32, (1, pc * bs), 1)                # (1, kk)
-        msk = kpos < pos_i                             # streamed = prefix
-        inw = jnp.where(win > 0, qp - kpos < win, True)
-        return fold(carry, kc, vc, jnp.logical_and(msk, inw))
+        shape = (tg, pc * bs)
+        kpos = base * bs + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        # streamed keys are the prefix below the row's first write
+        msk = jnp.logical_and(kpos < pos_i, query_pos(shape) - kpos < wlim)
+        return tuple(
+            fold(carry[hh], qs[hh],
+                 kbuf[:, :, hh, :].reshape(pc * bs, dh),
+                 vbuf[:, :, hh, :].reshape(pc * bs, dh), msk)
+            for hh in range(bh))
 
-    m0 = jnp.full((bh, group, t), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((bh, group, t), jnp.float32)
-    a0 = jnp.zeros((bh, group, t, dh), jnp.float32)
+    init = tuple((jnp.full((tg, 1), -jnp.inf, jnp.float32),
+                  jnp.zeros((tg, 1), jnp.float32),
+                  jnp.zeros((tg, dh), jnp.float32)) for _ in range(bh))
     start_i = start_r[i]
-    carry = jax.lax.fori_loop(start_i, start_i + nch_r[i], chunk,
-                              (m0, l0, a0))
+    carry = jax.lax.fori_loop(start_i, start_i + nch_r[i], chunk, init)
 
     # new tokens attend each other straight from VMEM (same values the
     # scatter just wrote), under the exact gather-path mask
-    knpos = pos_i + jax.lax.broadcasted_iota(jnp.int32, (1, t), 1)
-    msk = jnp.logical_and(knpos <= qp, knpos < virtual)
-    inw = jnp.where(win > 0, qp - knpos < win, True)
-    m, l, acc = fold(carry, kn_ref[...], vn_ref[...],
-                     jnp.logical_and(msk, inw))
-
-    out = acc / jnp.maximum(l[..., None], 1e-30)       # (bh, group, t, dh)
-    o_ref[...] = out.transpose(2, 0, 1, 3).astype(o_ref.dtype)
+    shape = (tg, t)
+    knpos = pos_i + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    qp = query_pos(shape)
+    msk = jnp.logical_and(jnp.logical_and(knpos <= qp, knpos < virtual),
+                          qp - knpos < wlim)
+    for hh in range(bh):
+        _, l, acc = fold(carry[hh], qs[hh], kn_ref[0, :, hh, :],
+                         vn_ref[0, :, hh, :], msk)
+        o_ref[0, hh] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def paged_attention(
@@ -278,25 +299,28 @@ def paged_attention(
     # from for an idle slot
     nch = jnp.where(pos >= virtual, 0, nch).astype(jnp.int32)
 
-    qg = q.reshape(b, t, hkv, group, dh)
+    # head-major query rows: (B, Hkv, T * group, Dh), row = tt * group + g
+    tg = t * group
+    qh = q.reshape(b, t, hkv, group, dh).transpose(0, 2, 1, 3, 4).reshape(
+        b, hkv, tg, dh)
     kernel = functools.partial(_kernel, virtual, t, bs, pc, bh, group, dh,
                                float(softcap))
+    # blocks keep their leading unit dim (a squeezed one would make the
+    # Mosaic verifier reject the tail-page DMA's slice of the block)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
         grid=(b, hkv // bh),
         in_specs=[
-            pl.BlockSpec((None, t, bh, group, dh),
-                         lambda i, j, *_: (i, 0, j, 0, 0)),
-            pl.BlockSpec((None, t, bh, dh), lambda i, j, *_: (i, 0, j, 0)),
-            pl.BlockSpec((None, t, bh, dh), lambda i, j, *_: (i, 0, j, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec((1, bh, tg, dh), lambda i, j, *_: (i, j, 0, 0)),
+            pl.BlockSpec((1, t, bh, dh), lambda i, j, *_: (i, 0, j, 0)),
+            pl.BlockSpec((1, t, bh, dh), lambda i, j, *_: (i, 0, j, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((None, t, bh, group, dh),
-                         lambda i, j, *_: (i, 0, j, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec((1, bh, tg, dh), lambda i, j, *_: (i, j, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         scratch_shapes=[
             pltpu.VMEM((pc, bs, bh, dh), k_pages.dtype),
@@ -310,7 +334,7 @@ def paged_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, t, hkv, group, dh), q.dtype),
+            jax.ShapeDtypeStruct((b, hkv, tg, dh), q.dtype),
             jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
             jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
         ],
@@ -319,6 +343,7 @@ def paged_attention(
         input_output_aliases={10: 1, 11: 2},
         interpret=interpret,
     )(routed, pos, start, nch, phys, off, win,
-      qg, knew.astype(k_pages.dtype), vnew.astype(v_pages.dtype),
+      qh, knew.astype(k_pages.dtype), vnew.astype(v_pages.dtype),
       k_pages, v_pages)
+    out = out.reshape(b, hkv, t, group, dh).transpose(0, 2, 1, 3, 4)
     return out.reshape(b, t, hq, dh), kp, vp

@@ -79,3 +79,45 @@ def scaled_matmul_ref(
     if bias is not None:
         y = y + bias.astype(jnp.float32)
     return y.astype(x.dtype)
+
+
+def paged_attention_ref(q, knew, vnew, k_pages, v_pages, tbl, pos, window,
+                        softcap):
+    """Oracle for the fused paged-attention kernel: the gather path of
+    ``models/attention.py`` in fp32 at full matmul precision.  Scatter
+    the T new tokens into their tail pages, materialise the
+    ``(B, MB*bs, Hkv, Dh)`` view through the table, mask causally and by
+    window, soft-capped SDPA.  Returns ``(out, k_pages, v_pages)``."""
+    b, t, hq, dh = q.shape
+    hkv = knew.shape[2]
+    n_pages, bs = k_pages.shape[0], k_pages.shape[1]
+    mb = tbl.shape[1]
+    virtual = mb * bs
+    qpos = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+    blk = jnp.minimum(qpos // bs, mb - 1)
+    phys = jnp.take_along_axis(tbl, blk, axis=1)
+    writable = jnp.logical_and(phys >= 0, qpos < virtual)
+    phys = jnp.where(writable, phys, n_pages - 1)
+    off = qpos % bs
+    k_pages = k_pages.at[phys, off].set(knew.astype(k_pages.dtype))
+    v_pages = v_pages.at[phys, off].set(vnew.astype(v_pages.dtype))
+    rt = jnp.where(tbl >= 0, tbl, 0)
+    ck = k_pages[rt].reshape(b, virtual, hkv, dh)
+    cv = v_pages[rt].reshape(b, virtual, hkv, dh)
+    kpos = jnp.arange(virtual, dtype=jnp.int32)[None, :]
+    causal = kpos[:, None, :] <= qpos[:, :, None]
+    inw = jnp.where(window > 0,
+                    qpos[:, :, None] - kpos[:, None, :] < window, True)
+    mask = jnp.logical_and(causal, inw)
+    group = hq // hkv
+    hi = jax.lax.Precision.HIGHEST
+    qg = q.reshape(b, t, hkv, group, dh).astype(jnp.float32)
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, ck.astype(jnp.float32),
+                   precision=hi) * dh**-0.5
+    if softcap > 0:
+        s = softcap * jnp.tanh(s / softcap)
+    s = jnp.where(mask[:, None, None], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bhgqk,bkhd->bqhgd", p, cv.astype(jnp.float32),
+                   precision=hi)
+    return o.reshape(b, t, hq, dh).astype(q.dtype), k_pages, v_pages

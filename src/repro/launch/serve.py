@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import cache as cache_mod
 from repro.configs import registry
 from repro.dist import steps as steps_mod
 from repro.models import get_model
@@ -123,6 +124,9 @@ def build_obs(args) -> Observability:
 
 
 def run_engine(model, cfg, params, args, rng):
+    """Serve ``args.requests`` ragged requests through one ``Engine`` and
+    print its summary; returns ``(engine, requests)`` for the caller to
+    inspect."""
     obs = build_obs(args)
     eng = Engine(model, cfg, params, n_slots=args.slots,
                  max_len=args.prompt_len + args.gen + 1,
@@ -210,9 +214,10 @@ def run_engine(model, cfg, params, args, rng):
     if obs.window is not None:
         print(f"[obs] profiler capture -> {args.profile_logdir} "
               f"(ticks {args.profile_ticks})")
+    return eng, reqs
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3_1_7b", choices=registry.ARCHS)
     ap.add_argument("--smoke", action="store_true")
@@ -288,14 +293,25 @@ def main(argv=None):
                         or args.profile_ticks):
         ap.error("--metrics-jsonl/--trace-out/--profile-ticks apply to "
                  "the engine path, not --static")
+    return args
 
+
+def build_model(args, rng):
+    """``(cfg, model, params)`` for the launcher flags, params drawn
+    from ``rng``."""
     cfg = (registry.get_smoke_config(args.arch) if args.smoke
            else registry.get_config(args.arch))
     cfg = registry.with_sell(cfg, args.sell, method=args.sell_method,
                              transform=args.sell_transform)
     model = get_model(cfg)
+    return cfg, model, model.init(rng, cfg)
+
+
+def main(argv=None):
+    cache_mod.configure_compile_cache()
+    args = parse_args(argv)
     rng = jax.random.PRNGKey(0)
-    params = model.init(rng, cfg)
+    cfg, model, params = build_model(args, rng)
     print(f"arch={cfg.name} sell={cfg.sell_kind} slots={args.slots}")
 
     if args.static:

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import cache as cache_mod
 from repro.checkpoint import CheckpointManager
 from repro.configs import registry
 from repro.data import DataConfig, SyntheticLM
@@ -95,7 +96,7 @@ def build(arch: str, smoke: bool, sell: str, seq_len: int,
     jitted = jax.jit(train_step, in_shardings=(state_sh, batch_sh),
                      out_shardings=(state_sh, metrics_sh),
                      donate_argnums=(0,))
-    return cfg, model, opt, mesh, jitted, pipeline, state_sh
+    return cfg, model, opt, mesh, jitted, pipeline, state_sh, batch_sh
 
 
 def _train_metrics():
@@ -177,7 +178,7 @@ def _restore(ckpt, step, model, cfg, opt, compress_dp, state_sh):
     return state
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3_1_7b", choices=registry.ARCHS)
     ap.add_argument("--smoke", action="store_true",
@@ -197,7 +198,9 @@ def main(argv=None):
     ap.add_argument("--accum-steps", type=int, default=1)
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
-    ap.add_argument("--ckpt-every", type=int, default=25)
+    ap.add_argument("--ckpt-every", type=int, default=25,
+                    help="steps between checkpoints; 0 saves none, not "
+                         "even the final one")
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--log-every", type=int, default=5)
     ap.add_argument("--metrics-jsonl", default=None, metavar="PATH",
@@ -210,19 +213,24 @@ def main(argv=None):
                     help="resolve the mesh via ElasticPolicy from however "
                          "many devices survived (elastic restart drill); "
                          "0 = plain host mesh")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
+
+
+def main(argv=None):
+    """Train for ``--steps`` steps; returns ``(losses, state)``: the loss
+    of every step this run took and the final train state."""
+    args = parse_args(argv)
+    cache_mod.configure_compile_cache()
 
     mesh = None
     if args.model_parallel > 0:
         pol = elastic.ElasticPolicy(model_parallel=args.model_parallel)
         dshape = pol.resolve_mesh(len(jax.devices()))
-        n = dshape[0] * dshape[1]
-        mesh = jax.sharding.Mesh(
-            np.array(jax.devices()[:n]).reshape(dshape), ("data", "model"))
+        mesh = make_host_mesh(dshape[1], n_devices=dshape[0] * dshape[1])
         print(f"[elastic] resolved mesh data={dshape[0]} model={dshape[1]} "
               f"from {len(jax.devices())} devices", flush=True)
 
-    cfg, model, opt, mesh, jitted, pipeline, state_sh = build(
+    cfg, model, opt, mesh, jitted, pipeline, state_sh, batch_sh = build(
         args.arch, args.smoke, args.sell, args.seq_len, args.global_batch,
         args.lr, args.steps, args.accum_steps, mesh=mesh,
         compress_grads=args.compress_grads, sell_method=args.sell_method,
@@ -237,7 +245,7 @@ def main(argv=None):
                               every=args.log_every, clock=time.time)
                 if args.metrics_jsonl else None)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         start_step = 0
         if args.resume and ckpt.latest_step() is not None:
             latest = ckpt.latest_step()
@@ -259,16 +267,18 @@ def main(argv=None):
             print(f"[compress] grad wire bytes {wire} vs fp32 {raw} "
                   f"({wire / max(raw, 1):.3f}x)", flush=True)
 
+        losses = []
         for step in range(start_step, args.steps):
             t0 = time.time()
-            batch = pipeline.batch_at(step)
+            batch = jax.device_put(pipeline.batch_at(step), batch_sh)
             state, metrics = jitted(state, batch)
             # sync before timing: dispatch is async, so the unblocked wall
             # time is just the enqueue cost (~ms) — the straggler monitor
             # would seed its EWMA from that and flag every real measurement
             jax.block_until_ready(metrics)
             dt = time.time() - t0
-            obs["loss"].set(float(metrics["loss"]))
+            losses.append(float(metrics["loss"]))
+            obs["loss"].set(losses[-1])
             obs["tps"].set(args.global_batch * args.seq_len / max(dt, 1e-9))
             obs["step_s"].observe(dt)
             if step % args.log_every == 0 or step == args.steps - 1:
@@ -297,12 +307,14 @@ def main(argv=None):
             # the drain path — it would mislabel a mid-run state as
             # ``args.steps`` and a resumed job would think training is done.
             ckpt.wait()
-            ckpt.save(args.steps, state, extra={"arch": args.arch})
+            if args.ckpt_every:
+                ckpt.save(args.steps, state, extra={"arch": args.arch})
     if exporter is not None:
         exporter.close()
         print(f"[obs] metrics jsonl -> {args.metrics_jsonl} "
               f"({exporter.exports} snapshots)", flush=True)
     print("done.")
+    return losses, state
 
 
 if __name__ == "__main__":

@@ -315,7 +315,7 @@ def _attention_paged(
         out, k_pages, v_pages = paged_attn_mod.paged_attention(
             q, k, v, k_pages, v_pages, block_tables, position, window,
             softcap=cfg.attn_logit_softcap, page_chunk=pc, head_block=bh,
-            interpret=ops._INTERPRET)
+            interpret=ops.interpret_mode())
     else:
         blk_idx = jnp.minimum(pos // bs, mb - 1)                       # (B,T)
         phys = jnp.take_along_axis(block_tables, blk_idx, axis=1)      # (B,T)

@@ -13,11 +13,12 @@ first-class feature of the framework rather than a bolt-on.
 
 from __future__ import annotations
 
-from typing import Optional
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.core import sell as sell_mod
 from repro.models.common import ModelConfig
@@ -61,32 +62,49 @@ def linear_init(
     return {"w": scale * jax.random.normal(rng, (n_in, n_out), dtype)}
 
 
-def _batch_local_constraint(x: jax.Array, batch_axes=()) -> jax.Array:
+def _batch_axes(cfg: ModelConfig) -> tuple:
+    """The mesh axes a SELL activation's batch dim shards over:
+    ``cfg.sell_batch_axes`` when set, else the ``pod``/``data`` axes of
+    the mesh in context (``jax.set_mesh``); empty with no mesh."""
+    if cfg.sell_batch_axes:
+        return tuple(cfg.sell_batch_axes)
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return ()
+    return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
+
+
+def _batch_spec(x: jax.Array, batch_axes: tuple) -> P:
+    spec = [None] * x.ndim
+    spec[0] = batch_axes if len(batch_axes) > 1 else batch_axes[0]
+    return P(*spec)
+
+
+def _batch_local_constraint(x: jax.Array, batch_axes: tuple) -> jax.Array:
     """Constrain a SELL input/output to batch-only sharding.
 
     The DCT/FFT inside a SELL mixes the ENTIRE feature axis, so if the
     activation arrives feature-sharded (tensor-parallel layout), SPMD must
-    all-gather it for every transform — measured at +119x collective bytes
-    on qwen3.train_4k (EXPERIMENTS.md section Perf, hillclimb #3, refuted
-    step).  Pinning SELL activations to (batch-sharded, feature-local)
-    keeps the O(N log N) transform collective-free; the O(N) diagonals are
-    replicated anyway.
+    all-gather it for every transform.  Pinning SELL activations to
+    (batch-sharded, feature-local) keeps the O(N log N) transform
+    collective-free; the O(N) diagonals are replicated anyway.
     """
-    try:
-        if not batch_axes:
-            mesh = jax.sharding.get_abstract_mesh()
-            if mesh is None or not mesh.axis_names:
-                return x
-            batch_axes = tuple(a for a in ("pod", "data")
-                               if a in mesh.axis_names)
-        if not batch_axes:
-            return x
-        spec = [None] * x.ndim
-        spec[0] = tuple(batch_axes) if len(batch_axes) > 1 else batch_axes[0]
-        return jax.lax.with_sharding_constraint(
-            x, jax.sharding.PartitionSpec(*spec))
-    except Exception:  # outside a mesh context (tests, examples)
+    if not batch_axes:
         return x
+    return jax.lax.with_sharding_constraint(x, _batch_spec(x, batch_axes))
+
+
+def _structured_linear(p: dict, x: jax.Array, scfg, batch_axes: tuple):
+    """``sell.structured_linear``; on a mesh of several devices the Pallas
+    kernels run under ``shard_map``, one batch shard per device, since
+    XLA cannot partition a Mosaic kernel."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if scfg.method != "pallas" or mesh.empty or mesh.size == 1:
+        return sell_mod.structured_linear(p, x, scfg)
+    spec = _batch_spec(x, batch_axes) if batch_axes else P()
+    return jax.shard_map(
+        functools.partial(sell_mod.structured_linear, cfg=scfg),
+        in_specs=(P(), spec), out_specs=spec, check_vma=False)(p, x)
 
 
 def linear_apply(
@@ -99,11 +117,12 @@ def linear_apply(
 ) -> jax.Array:
     if "sell" in params:
         scfg = _sell_cfg(cfg, n_in, n_out)
+        batch_axes = _batch_axes(cfg)
         if cfg.sell_local_features:
-            x = _batch_local_constraint(x, cfg.sell_batch_axes)
-        y = sell_mod.structured_linear(params["sell"], x, scfg)
+            x = _batch_local_constraint(x, batch_axes)
+        y = _structured_linear(params["sell"], x, scfg, batch_axes)
         if cfg.sell_local_features:
-            y = _batch_local_constraint(y, cfg.sell_batch_axes)
+            y = _batch_local_constraint(y, batch_axes)
         return y
     return jnp.matmul(x, params["w"].astype(x.dtype))
 

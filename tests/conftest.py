@@ -4,28 +4,21 @@ real single CPU device; only launch/dryrun.py forces 512 placeholders."""
 import jax
 import pytest
 
-# jax < 0.5 constructs AbstractMesh from shape_tuple=((name, size), ...);
-# newer releases take (axis_sizes, axis_names).  The sharding tests use the
-# newer calling convention — adapt on old installs so one suite serves both.
-try:
-    jax.sharding.AbstractMesh((1,), ("_probe",))
-except TypeError:
-    _ABSTRACT_MESH = jax.sharding.AbstractMesh
-
-    def _abstract_mesh_compat(axis_sizes, axis_names=None, *args, **kwargs):
-        if axis_names is None:
-            return _ABSTRACT_MESH(axis_sizes, *args, **kwargs)
-        return _ABSTRACT_MESH(tuple(zip(axis_names, axis_sizes)),
-                              *args, **kwargs)
-
-    jax.sharding.AbstractMesh = _abstract_mesh_compat
-except AttributeError:
-    pass  # jax predates AbstractMesh: let the tests that need it fail alone
-
 
 @pytest.fixture(scope="session")
 def rng():
     return jax.random.PRNGKey(0)
+
+
+@pytest.fixture(autouse=True)
+def _no_compile_cache(monkeypatch):
+    """The launchers place JAX's persistent compilation cache inside the
+    checkout; tests that call them must not turn it on for the rest of
+    their worker process (``tests/test_cache.py`` tests the helper)."""
+    from repro import cache as cache_mod
+
+    monkeypatch.setattr(cache_mod, "configure_compile_cache",
+                        lambda: str(cache_mod.COMPILE_CACHE_DIR))
 
 
 def pytest_configure(config):

@@ -11,7 +11,8 @@ Captures, on CPU (the CI backend the pins run on):
 into ``acdc_goldens.json``.  ``tests/test_families.py`` asserts the live
 code reproduces both EXACTLY (token equality, bitwise float equality) —
 the guard that the pluggable-transform refactor left the paper's DCT
-family untouched.  Only regenerate after an intentional numerics change.
+family untouched.  Only regenerate after an intentional numerics change
+or a JAX upgrade (the file records the version that wrote it).
 """
 
 import json
@@ -84,6 +85,9 @@ def cascade_grads():
 def main():
     out = {
         "backend": jax.default_backend(),
+        # jax.random draws and CPU codegen both change between releases:
+        # the pins hold for the version that wrote them
+        "jax_version": jax.__version__,
         "engine": engine_streams(),
         "cascade_vjp": cascade_grads(),
     }

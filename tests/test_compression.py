@@ -61,7 +61,7 @@ def test_compressed_psum_single_device_semantics():
     """On a 1-member axis, compressed_psum returns the dequantized local
     gradient and the quantization residual as new error."""
     from jax.sharding import Mesh
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = jax.make_mesh((1,), ("pod",))

@@ -241,14 +241,20 @@ def test_reverse_sweep_backward_matches_scan_oracle(relu, permute, dtype,
     g = jax.random.normal(jax.random.fold_in(r, 4), (rows, n), dtype)
 
     got = ops._cascade_bwd_fused(relu, permute, x, a, d, b, g)
-    want = ops._cascade_bwd_core(relu, permute, x, a, d, b, g)
-    # bf16: the scan oracle casts the rematerialized activations back to
-    # bf16 between layers while the reverse sweep (like the fused
-    # forward) keeps them fp32 on-chip — compare loosely.
-    atol = 2e-4 if dtype == jnp.float32 else 0.15
-    rtol = 1e-3 if dtype == jnp.float32 else 0.1
+    # bf16: the reverse sweep (like the fused forward) keeps the
+    # rematerialized activations fp32 on-chip, so its oracle is the scan
+    # in fp32 on the same bf16 inputs.  A bf16 scan is no oracle: it
+    # rounds every layer's activations back to bf16, and where a ReLU
+    # input sits near zero that flips the mask and moves dd by ~7% of
+    # its scale, while the reverse sweep stays within bf16 output
+    # rounding of the fp32 scan.
+    f32 = jnp.float32
+    want = ops._cascade_bwd_core(relu, permute, x.astype(f32), a, d, b,
+                                 g.astype(f32))
+    atol = 2e-4 if dtype == jnp.float32 else 3e-2
+    rtol = 1e-3 if dtype == jnp.float32 else 1e-2
     for name, gv, wv in zip(("dx", "da", "dd", "db"), got, want):
-        assert gv.dtype == wv.dtype, name
+        assert gv.dtype == (dtype if name == "dx" else jnp.float32), name
         np.testing.assert_allclose(
             np.asarray(gv, np.float32), np.asarray(wv, np.float32),
             atol=atol, rtol=rtol, err_msg=f"{name} relu={relu} "

@@ -29,6 +29,7 @@ import pytest
 from repro.configs import registry
 from repro.kernels import ops
 from repro.kernels import paged_attn
+from repro.kernels.ref import paged_attention_ref
 from repro.models import get_model
 from repro.serving import Engine, Request
 from repro.spec import ModelDraft
@@ -37,42 +38,6 @@ from repro.spec import ModelDraft
 # ---------------------------------------------------------------------------
 # Direct kernel parity vs the block-table gather (no engine).
 # ---------------------------------------------------------------------------
-
-def _gather_ref(q, knew, vnew, k_pages, v_pages, tbl, pos, window, softcap):
-    """The gather path's math, transcribed from models/attention.py:
-    scatter the new tokens, materialise the (B, virtual, Hkv, Dh) view
-    through the routed table, mask causally + by window, soft-capped SDPA."""
-    b, t, hq, dh = q.shape
-    hkv = knew.shape[2]
-    n_pages, bs = k_pages.shape[0], k_pages.shape[1]
-    mb = tbl.shape[1]
-    virtual = mb * bs
-    qpos = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
-    blk = jnp.minimum(qpos // bs, mb - 1)
-    phys = jnp.take_along_axis(tbl, blk, axis=1)
-    writable = jnp.logical_and(phys >= 0, qpos < virtual)
-    phys = jnp.where(writable, phys, n_pages - 1)
-    off = qpos % bs
-    k_pages = k_pages.at[phys, off].set(knew.astype(k_pages.dtype))
-    v_pages = v_pages.at[phys, off].set(vnew.astype(v_pages.dtype))
-    rt = jnp.where(tbl >= 0, tbl, 0)
-    ck = k_pages[rt].reshape(b, virtual, hkv, dh)
-    cv = v_pages[rt].reshape(b, virtual, hkv, dh)
-    kpos = jnp.arange(virtual, dtype=jnp.int32)[None, :]
-    causal = kpos[:, None, :] <= qpos[:, :, None]
-    inw = jnp.where(window > 0,
-                    qpos[:, :, None] - kpos[:, None, :] < window, True)
-    mask = jnp.logical_and(causal, inw)
-    group = hq // hkv
-    qg = q.reshape(b, t, hkv, group, dh).astype(jnp.float32)
-    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, ck.astype(jnp.float32)) * dh**-0.5
-    if softcap > 0:
-        s = softcap * jnp.tanh(s / softcap)
-    s = jnp.where(mask[:, None, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgqk,bkhd->bqhgd", p, cv.astype(jnp.float32))
-    return o.reshape(b, t, hq, dh).astype(q.dtype), k_pages, v_pages
-
 
 def _seq_tables(b, mb, nb):
     t = np.arange(b * mb, dtype=np.int32).reshape(b, mb)
@@ -129,8 +94,8 @@ def test_kernel_matches_gather(case):
     tbl = tables(b, mb, nb)
     pos = jnp.asarray(positions, jnp.int32)
     win = jnp.int32(c["window"])
-    ro, rk, rv = _gather_ref(q, knew, vnew, kp, vp, tbl, pos, win,
-                             c["softcap"])
+    ro, rk, rv = paged_attention_ref(q, knew, vnew, kp, vp, tbl, pos, win,
+                                     c["softcap"])
     fo, fk, fv = jax.jit(lambda *a: paged_attn.paged_attention(
         *a, softcap=c["softcap"], page_chunk=pc, head_block=bh,
         interpret=True))(q, knew, vnew, kp, vp, tbl, pos, win)

@@ -28,6 +28,7 @@ from repro.models import get_model
 from repro.serving import BlockAllocator, Engine, Request, Scheduler
 from repro.spec import ModelDraft, TruncatedCascadeDraft
 from repro.spec import verify as verify_mod
+from repro.spec.draft import truncate_cascades
 
 SPEC_ARCHS = ["qwen3_1_7b", "seamless_m4t_large_v2", "zamba2_1_2b"]
 
@@ -221,13 +222,36 @@ def test_truncated_cascade_half_depth_acceptance(acdc_target):
 
 def test_truncated_cascade_acceptance_monotone_in_depth(acdc_target):
     """Deeper truncations approximate the target better (sections 3-4
-    depth result): acceptance rises with draft depth, reaching exactly
-    1.0 at full depth (the draft IS the target)."""
-    a1 = _acceptance_at_depth(acdc_target, 1)
-    a2 = _acceptance_at_depth(acdc_target, 2)
-    a4 = _acceptance_at_depth(acdc_target, 4)
-    assert a1 <= a2 + 1e-9 <= a4 + 2e-9
-    assert a4 == 1.0
+    depth result): on the target's own streams (teacher forcing), the
+    draft's logit error falls strictly with depth, to zero at full depth,
+    where the draft IS the target and a speculative run accepts every
+    draft.
+
+    Acceptance itself is not monotone at this size.  One speculative run
+    is a few dozen drafts whose contexts depend on earlier acceptances:
+    over parameter seeds 0-3 depth 1 out-accepted depth 2 in two runs.
+    Even the teacher-forced greedy agreement over these ~75 positions
+    went 0.88, 0.84, 0.93, 1.0 for depths 1-4, while the logit error fell
+    with depth for every seed tried."""
+    cfg, model, params, _, dense_reqs = acdc_target
+    width = max(r.prompt_len + len(r.generated) for r in dense_reqs)
+    tokens = np.zeros((len(dense_reqs), width), np.int32)
+    valid = np.zeros((len(dense_reqs), width), bool)
+    for i, r in enumerate(dense_reqs):
+        seq = list(r.prompt) + list(r.generated)
+        tokens[i, :len(seq)] = seq
+        valid[i, :len(seq)] = True
+    target = model.apply(params, jnp.asarray(tokens), cfg)[valid]
+    errors = []
+    for depth in range(1, cfg.sell_k + 1):
+        dcfg = dataclasses.replace(cfg, sell_k=depth)
+        logits = get_model(dcfg).apply(truncate_cascades(params, depth),
+                                       jnp.asarray(tokens), dcfg)[valid]
+        errors.append(float(jnp.linalg.norm(logits - target)
+                            / jnp.linalg.norm(target)))
+    assert all(e > f for e, f in zip(errors, errors[1:])), errors
+    assert errors[-1] == 0.0
+    assert _acceptance_at_depth(acdc_target, cfg.sell_k) == 1.0
 
 
 def test_truncated_cascade_skip_top_layers(acdc_target):
