@@ -23,13 +23,8 @@ Four modes over the same smoke-scale model and workload:
   the block-table gather, asserting token-identical streams and that the
   dispatch counters recorded only fused decisions.
 
-An analytic ``attn_bytes_model`` section accompanies the paged rows: the
-engine's per-tick ``attn_gather_bytes`` / ``attn_kernel_bytes`` counters
-(model, not measurement — both advance whichever path ran), plus the same
-workload re-run with a doubled page table to pin the memory-model claim:
-gather traffic scales with ``max_len`` while the kernel's is a function
-of live lengths only.  Wall-clock for ``paged_fused`` is reported but
-tagged ``non_roofline`` off-TPU, where the kernel runs interpreted.
+Wall-clock for ``paged_fused`` is reported but tagged ``non_roofline``
+off-TPU, where the kernel runs interpreted.
 
 ``--spec`` adds an A/B pair on an ACDC SELL smoke model: ``spec_baseline``
 (the plain continuous engine) vs ``spec`` (truncated-cascade self-draft +
@@ -151,16 +146,14 @@ def bench_continuous(model, cfg, params, n_slots: int, prompt_len: int,
                      gen: int, n_requests: int, paged: bool = False,
                      block_size: int = 16, n_blocks=None, spec_k: int = 0,
                      draft_depth=None, mode: str = None,
-                     force_fused: bool = False, max_len: int = None):
+                     force_fused: bool = False):
     """Ragged Poisson-ish stream: arrivals are interleaved with ticks.
 
     Returns (row, requests) so the paged run can be checked token-for-token
     against the dense run and the pool can be sized from actual demand.
     ``spec_k > 0`` serves the same workload speculatively (truncated-cascade
     self-draft at ``draft_depth``).  ``force_fused`` routes paged attention
-    through the fused streaming kernel regardless of backend;
-    ``max_len`` overrides the per-slot ceiling (used to grow the page
-    table without changing the workload, for the bytes model).
+    through the fused streaming kernel regardless of backend.
     """
     reqs = make_ragged_requests(cfg.vocab_size, n_requests, prompt_len, gen,
                                 vary_budget=True)
@@ -175,7 +168,7 @@ def bench_continuous(model, cfg, params, n_slots: int, prompt_len: int,
     dispatches_before = dict(ops.PAGED_ATTN_DISPATCHES)
     try:
         eng = Engine(model, cfg, params, n_slots=n_slots,
-                     max_len=max_len or (prompt_len + gen + 1),
+                     max_len=prompt_len + gen + 1,
                      max_prompt_len=prompt_len,
                      paged=paged, block_size=block_size, n_blocks=n_blocks,
                      spec_k=spec_k, draft_depth=draft_depth)
@@ -227,11 +220,6 @@ def bench_continuous(model, cfg, params, n_slots: int, prompt_len: int,
         "cache_bytes": eng.cache_bytes,
     }
     if paged:
-        ticks = max(row["decode_ticks"], 1)
-        gather_b = (eng.stats["attn_gather_bytes"]
-                    - warm_stats["attn_gather_bytes"])
-        kernel_b = (eng.stats["attn_kernel_bytes"]
-                    - warm_stats["attn_kernel_bytes"])
         row.update({
             "block_size": eng.block_size,
             "pool_blocks": eng.allocator.n_blocks,
@@ -241,10 +229,6 @@ def bench_continuous(model, cfg, params, n_slots: int, prompt_len: int,
             "stalled_slot_ticks": eng.stats["stalled_slot_ticks"]
             - warm_stats["stalled_slot_ticks"],
             "preempted": eng.stats["preempted"] - warm_stats["preempted"],
-            "attn_gather_bytes": gather_b,
-            "attn_kernel_bytes": kernel_b,
-            "attn_gather_bytes_per_tick": gather_b / ticks,
-            "attn_kernel_bytes_per_tick": kernel_b / ticks,
             "attn_dispatches": {
                 k: ops.PAGED_ATTN_DISPATCHES[k] - dispatches_before[k]
                 for k in dispatches_before},
@@ -541,14 +525,6 @@ def main(csv: bool = True, argv=None):
         model, cfg, params, args.slots, args.prompt_len, args.gen,
         args.requests, paged=True, block_size=args.block_size,
         n_blocks=pool, force_fused=True, mode="paged_fused")
-    # same workload, page table doubled: only the gather's analytic
-    # traffic may move (the byte counters are path-independent, so the
-    # cheap gather route is fine here)
-    virtual = paged["max_blocks_per_slot"] * args.block_size
-    paged2x, paged2x_reqs = bench_continuous(
-        model, cfg, params, args.slots, args.prompt_len, args.gen,
-        args.requests, paged=True, block_size=args.block_size,
-        n_blocks=pool, max_len=2 * virtual, mode="paged_2x_table")
     rows = [
         bench_sequential(model, cfg, params, prompts, args.gen),
         bench_batched_prefill(model, cfg, params, prompts, args.gen),
@@ -570,23 +546,13 @@ def main(csv: bool = True, argv=None):
         assert p.generated == d.generated, (
             f"rid={d.rid}: paged stream diverged from dense")
     # fused-kernel acceptance: token-identical to the gather run, only
-    # fused dispatches recorded, and the analytic attention traffic of
-    # the streaming kernel strictly below the gather's — and unchanged
-    # when the page table doubles, while the gather's doubles with it
+    # fused dispatches recorded
     for g, f in zip(paged_reqs, fused_reqs):
         assert f.generated == g.generated, (
             f"rid={g.rid}: paged_fused stream diverged from paged")
     assert fused["attn_dispatches"]["fused"] > 0
     assert fused["attn_dispatches"]["gather"] == 0, (
         "paged_fused run fell back to the gather path")
-    assert 0 < paged["attn_kernel_bytes"] < paged["attn_gather_bytes"]
-    for g, p2 in zip(paged_reqs, paged2x_reqs):
-        assert p2.generated == g.generated
-    assert paged2x["attn_kernel_bytes"] == paged["attn_kernel_bytes"], (
-        "kernel bytes moved with the page-table length")
-    assert (paged2x["attn_gather_bytes"]
-            == 2 * paged["attn_gather_bytes"]), (
-        "gather bytes did not scale with the page-table length")
 
     out = {
         "backend": jax.default_backend(),
@@ -604,17 +570,6 @@ def main(csv: bool = True, argv=None):
             seq["ttft_s"] / max(bat["ttft_s"], 1e-9),
         "paged_cache_bytes_vs_dense":
             paged["cache_bytes"] / max(cont["cache_bytes"], 1),
-        "attn_bytes_model": {
-            "mb_pages_per_slot": paged["max_blocks_per_slot"],
-            "gather_bytes_per_tick": paged["attn_gather_bytes_per_tick"],
-            "kernel_bytes_per_tick": paged["attn_kernel_bytes_per_tick"],
-            "kernel_vs_gather":
-                paged["attn_kernel_bytes"] / paged["attn_gather_bytes"],
-            "gather_bytes_at_2x_table": paged2x["attn_gather_bytes"],
-            "kernel_bytes_at_2x_table": paged2x["attn_kernel_bytes"],
-            "kernel_mb_independent":
-                paged2x["attn_kernel_bytes"] == paged["attn_kernel_bytes"],
-        },
     }
     if args.spec:
         sbase, srow = rows[-2], rows[-1]
@@ -635,10 +590,7 @@ def main(csv: bool = True, argv=None):
                          f";peak_blocks={r['peak_blocks_in_use']}"
                          f"/{r['pool_blocks']}")
             if r["mode"] == "paged_fused":
-                extra = (f";attn_bytes_per_tick="
-                         f"{r['attn_kernel_bytes_per_tick']:.0f}"
-                         f"(gather={r['attn_gather_bytes_per_tick']:.0f})"
-                         f";dispatches=fused:{r['attn_dispatches']['fused']}"
+                extra = (f";dispatches=fused:{r['attn_dispatches']['fused']}"
                          f"/gather:{r['attn_dispatches']['gather']}")
             if r["mode"] == "spec":
                 extra = (f";acceptance={r['acceptance_rate']:.3f}"

@@ -14,8 +14,9 @@ serving engine, the kernels' dispatch counters, and both launchers:
   injectable monotonic clock (virtual-clock compatible), exported as
   Chrome/Perfetto trace-event JSON.
 * :mod:`repro.obs.prof` — ``jax.profiler`` named-scope annotations
-  around the engine's prefill/draft/verify/decode dispatches and an
-  on-demand capture window (``--profile-ticks A:B``).
+  around every phase of the engine's tick (the prefill/draft/verify/
+  decode dispatches among them) and an on-demand capture window
+  (``--profile-ticks A:B``).
 
 The noop fast path (default)
 ----------------------------
@@ -65,8 +66,6 @@ serve_spec_drafted_total            counter    draft tokens proposed
 serve_spec_accepted_total           counter    draft tokens accepted
 serve_acceptance_rate               derived    accepted/drafted AT SNAPSHOT
                                                time (never stale)
-serve_attn_gather_bytes_total       counter    analytic gather-path attn bytes
-serve_attn_kernel_bytes_total       counter    analytic fused-path attn bytes
 serve_ttft_seconds                  histogram  submit -> first token
 serve_tpot_seconds                  histogram  per-token decode latency
                                                (finish-ttft)/(n_tokens-1)
@@ -102,6 +101,39 @@ direction), ``deadline_preempt``, ``straggler``, ``fault:corrupt_logits``,
 ``fault:spurious_stall``, ``fault:slow_tick``.  Global-hook tracks:
 ``allocator`` (``audit``), ``autotune`` (``sweep`` with direction/key/
 winner), ``train`` (``straggler``).
+
+Profiler span glossary (:mod:`repro.obs.prof`)
+----------------------------------------------
+``TraceAnnotation`` names in a ``jax.profiler`` capture, on the host
+plane and on the device events' clock.  Names carry no arguments.
+One tick nests as indented:
+
+==================  ==================================================
+span                what the host does in it
+==================  ==================================================
+engine.tick         the whole of ``Engine.tick()``
+ engine.expire      deadline sweep of queued and active requests
+ engine.admit       backoff release, admission passes, deadline
+                    preemption; holds one ``prefill`` per admission
+  prefill           one admission's prefill (device programs inside
+                    it are prefill work)
+   prefill.inputs   page allocation and the uploads of the context
+   prefill.launch   the jitted prefill call until it returns
+   prefill.sample   the first token's sample; waits on the prefill
+ engine.map         (paged) mapping of each slot's write window
+ engine.rng         the decode key's ``fold_in`` dispatch
+ decode             the fused decode step (plain ticks)
+  decode.inputs     position copy, stall parking, uploads of tokens,
+                    positions and block table
+  decode.launch     the jitted step call until it returns
+  decode.wait       ``np.asarray`` of the tokens: blocked on the
+                    device and the copy back
+ draft              the draft's proposal (speculative ticks)
+ verify             the target's verify (speculative ticks), split
+                    into ``verify.inputs/launch/wait`` as decode is
+ engine.commit      per-slot token commit, finishes and page frees
+ engine.pressure    tick histogram, watchdog and degradation ladder
+==================  ==================================================
 """
 
 from __future__ import annotations

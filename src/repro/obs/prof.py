@@ -3,11 +3,18 @@
 Two cheap bridges between the serving/training host loops and JAX's own
 profiler, both default-off:
 
-* :class:`Prof` — ``prof.annotate("decode")`` wraps a host-side dispatch
-  in a ``jax.profiler.TraceAnnotation`` so prefill / decode / verify /
-  draft show up as named rows in a captured trace.  Disabled (the
-  default), ``annotate`` returns one shared no-op context manager —
-  no allocation, no jax call — which is the entirety of the engine's
+* :class:`Prof` — ``prof.annotate("decode")`` wraps a piece of host
+  work in a ``jax.profiler.TraceAnnotation``, so it shows up as a named
+  span in a captured trace, on the same clock as the device's programs.
+  The engine names every phase of its tick this way: ``engine.tick``
+  holds ``engine.expire``, ``engine.admit`` (with one ``prefill`` per
+  admission, split into ``prefill.inputs/launch/sample``),
+  ``engine.map``, ``engine.rng``, ``decode`` (split into
+  ``decode.inputs/launch/wait``; ``draft`` and ``verify`` on a
+  speculative tick), ``engine.commit`` and ``engine.pressure`` (the
+  glossary in ``repro/obs/__init__.py``).  Disabled (the default),
+  ``annotate`` returns one shared no-op context manager — no
+  allocation, no jax call — which is the entirety of the engine's
   profiling overhead when off.
 
 * :class:`ProfileWindow` — parses the launcher's ``--profile-ticks A:B``

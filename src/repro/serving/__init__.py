@@ -251,8 +251,6 @@ acceptance_rate      serve_acceptance_rate                 derived gauge
                                                            accepted /
                                                            drafted at
                                                            read time)
-attn_gather_bytes    serve_attn_gather_bytes_total         counter
-attn_kernel_bytes    serve_attn_kernel_bytes_total         counter
 ===================  ====================================  =============
 
 Latency histograms (``serve_ttft_seconds``, ``serve_tpot_seconds``,

@@ -120,8 +120,6 @@ STATS_METRICS = {
     "drafted": ("serve_spec_drafted_total", "counter"),
     "accepted": ("serve_spec_accepted_total", "counter"),
     "acceptance_rate": ("serve_acceptance_rate", "derived"),
-    "attn_gather_bytes": ("serve_attn_gather_bytes_total", "counter"),
-    "attn_kernel_bytes": ("serve_attn_kernel_bytes_total", "counter"),
 }
 
 
@@ -361,40 +359,6 @@ class Engine:
             total += self.draft.cache_bytes
         return total
 
-    def _attn_bytes_tick(self, pos: np.ndarray) -> None:
-        """Analytic attention K/V traffic for one paged decode/verify tick,
-        accumulated into ``stats`` (model, not a measurement):
-
-        * ``attn_gather_bytes`` — what the block-table *gather* path reads:
-          every K/V page pool is materialised as a ``(n_slots, virtual,
-          Hkv, Dh)`` view, so each layer costs ``n_slots * virtual`` tokens
-          regardless of how full any row is (O(max_blocks * block_size)
-          per slot).
-        * ``attn_kernel_bytes`` — what the fused streaming kernel reads:
-          per live row, only the mapped prefix ``ceil(pos / block_size)``
-          pages; parked and stalled rows cost nothing.  Window narrowing
-          and the chunk-granularity round-up are ignored, so this is a
-          slight over-estimate for sliding-window layers.
-
-        Both counters advance every paged tick whichever path actually
-        ran, so fused and gather runs of the same trace report identical
-        numbers and the ratio is a pure memory-model statement.
-        """
-        gather = kernel = 0
-        for path, leaf in jax.tree_util.tree_flatten_with_path(
-                self._cache)[0]:
-            if not any("pages" in str(k) for k in path):
-                continue
-            n_layers, bs = leaf.shape[0], leaf.shape[2]
-            tok_bytes = int(np.prod(leaf.shape[3:])) * leaf.dtype.itemsize
-            gather += n_layers * self.n_slots * self._virtual * tok_bytes
-            for p in pos:
-                p = int(p)
-                if p < self._virtual:
-                    kernel += n_layers * (-(-p // bs) * bs) * tok_bytes
-        self.stats["attn_gather_bytes"] += gather
-        self.stats["attn_kernel_bytes"] += kernel
-
     def _decode_rng(self, tick: int) -> jax.Array:
         return jax.random.fold_in(self._rng_decode, tick)
 
@@ -476,85 +440,106 @@ class Engine:
     def _admit_and_map(self) -> None:
         """Backoff release + admission + deadline preemption + (paged)
         mapping of this tick's write window."""
-        self._release_backoff()
-        self._admit_pass()
-        if self._deadline_preempt(self._clock()):
+        ann = self._prof.annotate
+        with ann("engine.admit"):
+            self._release_backoff()
             self._admit_pass()
+            if self._deadline_preempt(self._clock()):
+                self._admit_pass()
         if self.paged:
-            self._ensure_blocks(need=(self.spec_k_eff or 0) + 1)
+            with ann("engine.map"):
+                self._ensure_blocks(need=(self.spec_k_eff or 0) + 1)
 
     def tick(self) -> int:
         """Deadline sweep + admit + one fused decode step; returns
-        #active slots advanced."""
+        #active slots advanced.  Every piece of the tick runs in a named
+        ``Prof`` phase (``obs/prof.py``)."""
         tick_no = self._tick_no
         self._tick_no += 1
         if self._obs_tick is not None:    # exporter cadence + profile
             self._obs_tick(tick_no)       # window; None when neither set
-        self._expire_deadlines(self._clock())
-        t0 = self._timer()
-        if self.spec_k_eff:
-            n = self._tick_spec(tick_no)
-        else:
-            n = self._tick_decode(tick_no)
-        dt = self._timer() - t0
-        if self._fault is not None:
-            extra = self._fault.extra_tick_s(tick_no)
-            if extra and self._tracer is not None:
-                self._tracer.instant("engine", "fault:slow_tick",
-                                     tick=tick_no, extra_s=extra)
-            dt += extra
-        self._h_tick.observe(dt)
-        self._observe_pressure(dt, tick_no)
+        ann = self._prof.annotate
+        # opened after the hook: a profile window that starts here still
+        # records this tick's span
+        with ann("engine.tick"):
+            with ann("engine.expire"):
+                self._expire_deadlines(self._clock())
+            t0 = self._timer()
+            if self.spec_k_eff:
+                n = self._tick_spec(tick_no)
+            else:
+                n = self._tick_decode(tick_no)
+            with ann("engine.pressure"):
+                dt = self._timer() - t0
+                if self._fault is not None:
+                    extra = self._fault.extra_tick_s(tick_no)
+                    if extra and self._tracer is not None:
+                        self._tracer.instant("engine", "fault:slow_tick",
+                                             tick=tick_no, extra_s=extra)
+                    dt += extra
+                self._h_tick.observe(dt)
+                self._observe_pressure(dt, tick_no)
         return n
 
     def _tick_decode(self, tick_no: int) -> int:
         self._admit_and_map()
         active = self.scheduler.active()
-        if active:
+        if not active:
+            return 0
+        ann = self._prof.annotate
+        with ann("engine.rng"):
             rng = self._decode_rng(self.stats["decode_ticks"])
-            t0 = self._timer()
-            with self._prof.annotate("decode"):
+        t0 = self._timer()
+        with ann("decode"):
+            with ann("decode.inputs"):
                 if self.paged:
                     pos = self._positions.copy()
                     for slot in self._stalled:
                         pos[slot] = self._park  # no write/token this tick
-                    self._attn_bytes_tick(pos)
-                    tok, self._cache = self._decode(
-                        self.params, self._cache, jnp.asarray(self._tokens),
-                        jnp.asarray(pos), jnp.asarray(self.allocator.table),
-                        rng)
+                    args = (jnp.asarray(self._tokens), jnp.asarray(pos),
+                            jnp.asarray(self.allocator.table))
                 else:
-                    tok, self._cache = self._decode(
-                        self.params, self._cache, jnp.asarray(self._tokens),
-                        jnp.asarray(self._positions), rng)
+                    args = (jnp.asarray(self._tokens),
+                            jnp.asarray(self._positions))
+            with ann("decode.launch"):
+                tok, self._cache = self._decode(self.params, self._cache,
+                                                *args, rng)
+            with ann("decode.wait"):
                 tok_np = np.asarray(tok)
-            self.stats["decode_s"] += self._timer() - t0
-            self.stats["decode_ticks"] += 1
-            self.stats["stalled_slot_ticks"] += len(self._stalled)
-            if self._fault is not None and self._fault.logits_corrupt(
-                    tick_no):
-                # simulated NaN/inf logits: every sampled id is garbage
-                tok_np = np.full_like(tok_np, -1)
-                self.stats["corrupt_ticks"] += 1
-                if self._tracer is not None:
-                    self._tracer.instant("engine", "fault:corrupt_logits",
-                                         tick=tick_no)
-            now = self._clock()
-            for slot, req in active:
-                if slot in self._stalled:
-                    continue  # parked this tick: its sampled token is junk
-                t = int(tok_np[slot])
-                if not 0 <= t < self.cfg.vocab_size:
-                    # corrupt decode output: heal by recompute — requeue
-                    # and re-prefill rather than commit a garbage token
-                    self._heal_or_kill(slot, req, now)
-                    continue
-                req.generated.append(t)
-                self.stats["tokens_out"] += 1
-                self._positions[slot] += 1
-                self._tokens[slot] = t
-                self._maybe_finish(slot, req, t, now)
+        with ann("engine.commit"):
+            self._commit_decode(active, tok_np, tick_no, t0)
         return len(active)
+
+    def _commit_decode(self, active, tok_np: np.ndarray, tick_no: int,
+                       t0: float) -> None:
+        """Append each live slot's sampled token and retire the slots
+        that finished."""
+        self.stats["decode_s"] += self._timer() - t0
+        self.stats["decode_ticks"] += 1
+        self.stats["stalled_slot_ticks"] += len(self._stalled)
+        if self._fault is not None and self._fault.logits_corrupt(
+                tick_no):
+            # simulated NaN/inf logits: every sampled id is garbage
+            tok_np = np.full_like(tok_np, -1)
+            self.stats["corrupt_ticks"] += 1
+            if self._tracer is not None:
+                self._tracer.instant("engine", "fault:corrupt_logits",
+                                     tick=tick_no)
+        now = self._clock()
+        for slot, req in active:
+            if slot in self._stalled:
+                continue  # parked this tick: its sampled token is junk
+            t = int(tok_np[slot])
+            if not 0 <= t < self.cfg.vocab_size:
+                # corrupt decode output: heal by recompute — requeue
+                # and re-prefill rather than commit a garbage token
+                self._heal_or_kill(slot, req, now)
+                continue
+            req.generated.append(t)
+            self.stats["tokens_out"] += 1
+            self._positions[slot] += 1
+            self._tokens[slot] = t
+            self._maybe_finish(slot, req, t, now)
 
     def _tick_spec(self, tick_no: int) -> int:
         """One speculative tick: draft k, verify once, advance each slot
@@ -564,34 +549,41 @@ class Engine:
         active = self.scheduler.active()
         if not active:
             return 0
-        tick_rng = self._decode_rng(self.stats["decode_ticks"])
-        draft_rng = jax.random.fold_in(tick_rng, 0)
-        verify_rng = jax.random.fold_in(tick_rng, 1)
+        ann = self._prof.annotate
+        with ann("engine.rng"):
+            tick_rng = self._decode_rng(self.stats["decode_ticks"])
+            draft_rng = jax.random.fold_in(tick_rng, 0)
+            verify_rng = jax.random.fold_in(tick_rng, 1)
         pos = self._positions.copy()
         for slot in self._stalled:
             pos[slot] = self._park  # no writes, no tokens this tick
-        if self.paged:
-            self._attn_bytes_tick(pos)
 
         t0 = self._timer()
-        with self._prof.annotate("draft"):
+        with ann("draft"):
             drafts, draft_logits = self.draft.propose(self._tokens, pos,
                                                       draft_rng)
         tok_mat = np.concatenate([self._tokens[:, None], drafts],
                                  axis=1).astype(np.int32)
-        with self._prof.annotate("verify"):
-            if self.paged:
+        with ann("verify"):
+            with ann("verify.inputs"):
+                args = (jnp.asarray(tok_mat), jnp.asarray(drafts),
+                        draft_logits, jnp.asarray(pos))
+                if self.paged:
+                    args += (jnp.asarray(self.allocator.table),)
+            with ann("verify.launch"):
                 acc, out, self._cache = self._verify(
-                    self.params, self._cache, jnp.asarray(tok_mat),
-                    jnp.asarray(drafts), draft_logits, jnp.asarray(pos),
-                    jnp.asarray(self.allocator.table), verify_rng)
-            else:
-                acc, out, self._cache = self._verify(
-                    self.params, self._cache, jnp.asarray(tok_mat),
-                    jnp.asarray(drafts), draft_logits, jnp.asarray(pos),
-                    verify_rng)
-            acc_np = np.asarray(acc)
-            out_np = np.asarray(out)
+                    self.params, self._cache, *args, verify_rng)
+            with ann("verify.wait"):
+                acc_np = np.asarray(acc)
+                out_np = np.asarray(out)
+        with ann("engine.commit"):
+            self._commit_spec(active, k, acc_np, out_np, tick_no, t0)
+        return len(active)
+
+    def _commit_spec(self, active, k: int, acc_np: np.ndarray,
+                     out_np: np.ndarray, tick_no: int, t0: float) -> None:
+        """Advance each slot by its accepted length and roll back the
+        rest of its verify window."""
         self.stats["decode_s"] += self._timer() - t0
         self.stats["decode_ticks"] += 1
         self.stats["stalled_slot_ticks"] += len(self._stalled)
@@ -648,7 +640,6 @@ class Engine:
                         and slot not in self._stalled):
                     self.allocator.trim_slot(
                         slot, int(self._positions[slot]) + 1)
-        return len(active)
 
     @property
     def has_work(self) -> bool:
@@ -862,21 +853,29 @@ class Engine:
             self._tracer.req_phase(req.rid, "prefill", slot=slot,
                                    ctx_len=clen)
         t0 = self._timer()
-        with self._prof.annotate("prefill"):
-            if self.paged:
-                self.allocator.alloc_slot(slot, clen)
-                last_logits, self._cache = self._prefill(
-                    self.params, self._cache, self._slot_template,
-                    jnp.asarray(toks), lengths,
-                    jnp.asarray(self.allocator.phys_row(slot)),
-                    jnp.int32(slot), fe)
-            else:
-                last_logits, slot_cache = self._prefill(
-                    self.params, self._slot_template, jnp.asarray(toks),
-                    lengths, fe)
-                self._cache = self._insert(self._cache, slot_cache,
-                                           jnp.int32(slot))
-            tok = int(self._sample(self._admit_rng(req.rid), last_logits)[0])
+        ann = self._prof.annotate
+        with ann("prefill"):
+            with ann("prefill.inputs"):
+                if self.paged:
+                    self.allocator.alloc_slot(slot, clen)
+                    args = (self._slot_template, jnp.asarray(toks), lengths,
+                            jnp.asarray(self.allocator.phys_row(slot)),
+                            jnp.int32(slot), fe)
+                else:
+                    args = (self._slot_template, jnp.asarray(toks), lengths,
+                            fe)
+            with ann("prefill.launch"):
+                if self.paged:
+                    last_logits, self._cache = self._prefill(
+                        self.params, self._cache, *args)
+                else:
+                    last_logits, slot_cache = self._prefill(self.params,
+                                                            *args)
+                    self._cache = self._insert(self._cache, slot_cache,
+                                               jnp.int32(slot))
+            with ann("prefill.sample"):
+                tok = int(self._sample(self._admit_rng(req.rid),
+                                       last_logits)[0])
             if self.draft is not None:
                 # the draft mirrors the slot layout: its own (cheap)
                 # prefill fills its cache row so drafting starts from the

@@ -7,14 +7,21 @@ Three layers of assertions:
   merge/Prometheus, the derived-gauge staleness fix, the dict shims);
 * the tracer: contiguous per-request phase chains, exactly one terminal
   event per request, deterministic Chrome exports;
-* the engine: obs OFF binds no tracer/exporter/tick hook (the documented
-  noop path) and greedy token streams are identical with obs on and off;
+* the engine: obs OFF binds no tracer/exporter/tick hook and hands every
+  profiler span site the one shared no-op (the documented noop path);
+  greedy token streams are identical with obs and the profiler spans on
+  and off;
+* the tick's profiler phases (``obs/prof.py``): one ``engine.tick`` per
+  tick holds its phases in order, ``decode`` and ``prefill`` keep the
+  calls they held before the split, a speculative tick splits ``verify``
+  the same way, and a real ``jax.profiler`` capture carries every name;
   a seeded FaultPlan chaos run over a virtual clock yields a complete,
   well-formed, replay-deterministic trace covering every finish reason
   the run produced — including the engineered ``timeout``, ``rejected``
   and ``preempted_limit`` terminals.
 """
 
+import contextlib
 import json
 
 import jax
@@ -26,6 +33,7 @@ from repro.models import get_model
 from repro.obs import Observability
 from repro.obs.metrics import (CounterDict, JsonlExporter, Registry,
                                StatsView, merge_snapshots)
+from repro.obs import prof as prof_mod
 from repro.obs.prof import Prof, parse_tick_window
 from repro.obs.trace import SpanTracer, instant_global, set_global_tracer
 from repro.serving import Engine, FaultPlan, Request
@@ -288,6 +296,26 @@ def _reqs(cfg, n=4, seed=5, max_new=8, **kw):
             for i in range(n)]
 
 
+#: every span a plain paged tick can open (``repro/obs/__init__.py``)
+TICK_SPANS = {"engine.tick", "engine.expire", "engine.admit", "engine.map",
+              "engine.rng", "engine.commit", "engine.pressure", "decode",
+              "decode.inputs", "decode.launch", "decode.wait", "prefill",
+              "prefill.inputs", "prefill.launch", "prefill.sample"}
+
+
+class SpyProf(Prof):
+    """A disabled ``Prof`` that notes what each site was handed."""
+
+    def __init__(self):
+        super().__init__(enabled=False)
+        self.handed = []
+
+    def annotate(self, name):
+        ctx = super().annotate(name)
+        self.handed.append((name, ctx))
+        return ctx
+
+
 def test_engine_off_is_structurally_noop(smoke):
     cfg, model, params = smoke
     eng = Engine(model, cfg, params, n_slots=2, max_len=32,
@@ -301,6 +329,15 @@ def test_engine_off_is_structurally_noop(smoke):
     snap = eng.obs.registry.snapshot()
     assert snap["counters"]["serve_tokens_out_total"][""] == 2
     assert "serve_acceptance_rate" in snap["gauges"]
+    # every span site of a paged tick is handed the one shared no-op
+    spy = SpyProf()
+    eng = Engine(model, cfg, params, n_slots=2, max_len=32,
+                 max_prompt_len=16, paged=True, block_size=8,
+                 obs=Observability(prof=spy))
+    assert not eng.obs.enabled
+    eng.run(_reqs(cfg), max_ticks=400)
+    assert {n for n, _ in spy.handed} == TICK_SPANS
+    assert all(ctx is prof_mod._NULL for _, ctx in spy.handed)
 
 
 def test_engine_streams_identical_with_obs_on(smoke):
@@ -313,6 +350,184 @@ def test_engine_streams_identical_with_obs_on(smoke):
         eng.run(reqs, max_ticks=400)
         runs.append([r.generated for r in reqs])
     assert runs[0] == runs[1]
+
+
+class RecordingProf(Prof):
+    """An enabled ``Prof`` that records the span tree instead of writing
+    ``TraceAnnotation``s; ``call`` adds the engine calls it wraps as
+    leaves, so each span's contents can be read back."""
+
+    def __init__(self):
+        super().__init__(enabled=True)
+        self.roots = []
+        self._stack = []
+
+    def _open(self, name):
+        node = {"name": name, "in": []}
+        (self._stack[-1]["in"] if self._stack else self.roots).append(node)
+        return node
+
+    @contextlib.contextmanager
+    def annotate(self, name):
+        self._stack.append(self._open(name))
+        try:
+            yield
+        finally:
+            self._stack.pop()
+
+    def call(self, name, fn):
+        def wrapped(*a, **kw):
+            self._open("call:" + name)
+            return fn(*a, **kw)
+        return wrapped
+
+
+def _spans(node):
+    return [c["name"] for c in node["in"] if not c["name"].startswith("call:")]
+
+
+def _calls(node):
+    """The wrapped calls anywhere inside ``node``, in order."""
+    out = []
+    for c in node["in"]:
+        out += [c["name"][5:]] if c["name"].startswith("call:") else _calls(c)
+    return out
+
+
+def _child(node, name):
+    (c,) = [c for c in node["in"] if c["name"] == name]
+    return c
+
+
+def _cache(paged):
+    return dict(paged=True, block_size=8) if paged else {}
+
+
+def _recorded_run(smoke, paged=True, **kw):
+    """An engine under a ``RecordingProf``, run tick by tick:
+    ``(prof, ticks run, requests)``.  Five requests on two slots make
+    ticks that admit and ticks that only decode."""
+    cfg, model, params = smoke
+    prof = RecordingProf()
+    eng = Engine(model, cfg, params, n_slots=2, max_len=32,
+                 max_prompt_len=16, obs=Observability(prof=prof),
+                 **_cache(paged), **kw)
+    for name in ("_decode", "_decode_rng", "_prefill", "_sample",
+                 "_admit_rng") + (() if paged else ("_insert",)):
+        setattr(eng, name, prof.call(name, getattr(eng, name)))
+    if paged:
+        eng.allocator.alloc_slot = prof.call("alloc_slot",
+                                             eng.allocator.alloc_slot)
+    reqs = _reqs(cfg, n=5)
+    for r in reqs:
+        eng.submit(r)
+    ticks = 0
+    while eng.has_work:
+        eng.tick()
+        ticks += 1
+        assert ticks < 400
+    return prof, ticks, reqs
+
+
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
+def test_tick_phases_nest_and_keep_decode_and_prefill_contents(smoke,
+                                                               paged):
+    """One ``engine.tick`` per tick holds every span and call of it, its
+    phases in order; ``decode`` and ``prefill`` hold the calls they held
+    before the split (the decode key's ``fold_in`` stays outside)."""
+    prof, ticks, _ = _recorded_run(smoke, paged)
+    assert [r["name"] for r in prof.roots] == ["engine.tick"] * ticks
+    mapped = ["engine.map"] if paged else []
+    inputs = ["alloc_slot"] if paged else []
+    launch = ["_prefill"] if paged else ["_prefill", "_insert"]
+    admits = decodes = 0
+    for tick in prof.roots:
+        phases = _spans(tick)
+        assert _calls(tick) and not [c for c in tick["in"]
+                                     if c["name"].startswith("call:")]
+        assert phases in (
+            ["engine.expire", "engine.admit"] + mapped
+            + ["engine.pressure"],
+            ["engine.expire", "engine.admit"] + mapped
+            + ["engine.rng", "decode", "engine.commit", "engine.pressure"])
+        for pre in _child(tick, "engine.admit")["in"]:
+            admits += 1
+            assert pre["name"] == "prefill"
+            assert _spans(pre) == ["prefill.inputs", "prefill.launch",
+                                   "prefill.sample"]
+            assert _calls(pre) == inputs + launch + ["_admit_rng",
+                                                     "_sample"]
+            assert _calls(_child(pre, "prefill.inputs")) == inputs
+            assert _calls(_child(pre, "prefill.launch")) == launch
+        if "decode" in phases:
+            decodes += 1
+            dec = _child(tick, "decode")
+            assert _spans(dec) == ["decode.inputs", "decode.launch",
+                                   "decode.wait"]
+            assert _calls(dec) == ["_decode"]
+            assert _calls(_child(dec, "decode.launch")) == ["_decode"]
+            assert _calls(_child(tick, "engine.rng")) == ["_decode_rng"]
+        for quiet in ["engine.expire", "engine.pressure"] + mapped:
+            assert _calls(_child(tick, quiet)) == []
+    assert admits == 5 and decodes > 5
+
+
+def test_spec_tick_splits_verify_like_decode(smoke):
+    """A speculative tick: ``engine.rng`` holds the tick's key, ``draft``
+    and ``verify`` follow, and ``verify`` splits into inputs, launch and
+    wait as ``decode`` does."""
+    from repro.spec import ModelDraft
+
+    cfg, _, params = smoke
+    prof, _, _ = _recorded_run(smoke, spec_k=2,
+                               draft=ModelDraft(cfg, params=params))
+    spec_ticks = [t for t in prof.roots if "verify" in _spans(t)]
+    assert spec_ticks
+    for tick in spec_ticks:
+        assert _spans(tick) == ["engine.expire", "engine.admit",
+                                "engine.map", "engine.rng", "draft",
+                                "verify", "engine.commit",
+                                "engine.pressure"]
+        assert _spans(_child(tick, "verify")) == [
+            "verify.inputs", "verify.launch", "verify.wait"]
+        assert _calls(_child(tick, "engine.rng")) == ["_decode_rng"]
+
+
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
+def test_engine_streams_identical_with_prof_on(smoke, paged):
+    cfg, model, params = smoke
+    runs = []
+    for prof in (Prof(enabled=False), Prof(enabled=True), RecordingProf()):
+        reqs = _reqs(cfg, n=5)
+        eng = Engine(model, cfg, params, n_slots=2, max_len=32,
+                     max_prompt_len=16, obs=Observability(prof=prof),
+                     **_cache(paged))
+        eng.run(reqs, max_ticks=400)
+        runs.append([r.generated for r in reqs])
+    assert runs[0] == runs[1] == runs[2]
+
+
+def test_profiler_capture_names_every_tick_phase(smoke, tmp_path):
+    """A ``jax.profiler`` capture of a few ticks carries every phase name
+    on the host plane, which is where the benchmark's reduction reads
+    them."""
+    from jax.profiler import ProfileData
+
+    cfg, model, params = smoke
+    eng = Engine(model, cfg, params, n_slots=2, max_len=32,
+                 max_prompt_len=16, paged=True, block_size=8,
+                 obs=Observability(prof=Prof(enabled=True)))
+    eng.run(_reqs(cfg, n=1, max_new=2), max_ticks=50)     # compile first
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.run(_reqs(cfg, n=3, max_new=4), max_ticks=50)
+    finally:
+        jax.profiler.stop_trace()
+    (xplane,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    names = {ev.name for plane in ProfileData.from_file(str(xplane)).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events}
+    assert TICK_SPANS <= names
 
 
 def test_engine_acceptance_rate_is_derived(smoke):

@@ -13,10 +13,7 @@
   family in plain decode AND speculative verify;
 * page-recycling regression: pages freed by eviction and LIFO-remapped to
   a *different* slot mid-stream must not leak stale K/V through the causal
-  mask (dense parity across evict->admit cycles on a tight pool);
-* analytic ``attn_kernel_bytes`` / ``attn_gather_bytes`` engine counters:
-  kernel traffic strictly below gather's and independent of the per-slot
-  page-table length for a fixed stream.
+  mask (dense parity across evict->admit cycles on a tight pool).
 """
 
 import dataclasses
@@ -278,23 +275,3 @@ def test_page_recycling_no_stale_kv(served_arch, monkeypatch):
     total_pages_needed = sum(-(-(r.prompt_len + len(r.generated)) // BLOCK)
                              for r in f_reqs)
     assert total_pages_needed > 5
-
-
-def test_attn_byte_counters_stream_vs_gather(served_arch, monkeypatch):
-    """The analytic per-tick counters: kernel bytes strictly below gather
-    bytes, and independent of the page-table length (max_len) while
-    gather's scale with it."""
-    cfg, model, params, make_requests, _ = served_arch
-    _, eng1 = _run_paged((cfg, model, params), make_requests, False,
-                         monkeypatch)
-    g1, k1 = eng1.stats["attn_gather_bytes"], eng1.stats["attn_kernel_bytes"]
-    assert 0 < k1 < g1
-    # double max_len => double the per-slot page table; same streams
-    monkeypatch.setattr(paged_attn, "FORCE_FUSED", False)
-    reqs = make_requests()
-    eng2 = Engine(model, cfg, params, n_slots=N_SLOTS, max_len=2 * MAX_LEN,
-                  max_prompt_len=MAX_PROMPT, paged=True, block_size=BLOCK)
-    eng2.run(reqs, max_ticks=600)
-    g2, k2 = eng2.stats["attn_gather_bytes"], eng2.stats["attn_kernel_bytes"]
-    assert k2 == k1        # streamed bytes depend on lengths, not max_len
-    assert g2 == 2 * g1    # gathered bytes scale with the virtual row
