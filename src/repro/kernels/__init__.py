@@ -31,8 +31,15 @@ Layout (per repo convention):
   in-place).  One body serves both grids: decode (T=1) and speculative
   verify (T=k+1).  The ``(B, virtual, Hkv, Dh)`` gather view is never
   materialised.
+* ``acdc_factored.py``      — per-layer forward above ``MAX_FUSED_N`` for
+  the DCT family (N a multiple of 128): the DCT factored four-step style
+  into N1 x N1 and 128 x 128 complex stages around a twiddle diagonal,
+  all operands resident in VMEM, one call per row block — no N x N
+  matrix is streamed.  The served path of every large ACDC projection.
 * ``scaled_matmul.py``      — blocked (m,n,k) scaled matmul kernel; the
-  building block of every > ``MAX_FUSED_N`` regime.
+  building block of the other > ``MAX_FUSED_N`` regimes: the per-layer
+  backward, and the forward of the other families and of N not a
+  multiple of 128.
 * ``autotune.py``           — first-call on-device row-block sweep
   ({64, 128, 256}, memoized per (N, K, dtype, direction) and persisted
   to ``.cache/autotune.json`` for device runs) feeding ``bm`` to
@@ -85,10 +92,17 @@ operands, so every real-orthonormal family runs the SAME kernels — the
 family only changes which matrices ``ops.py`` feeds them and which key
 the autotuner sweeps under::
 
-    family      fused fwd   fused bwd   cascade fwd   cascade bwd   notes
-    acdc        yes         yes         yes           yes           DCT-II
-    circulant   yes         yes         yes           yes           real-DFT
-    hadamard    yes         yes         yes           yes           pow2 N
+    family      fused fwd   fused bwd   cascade fwd   cascade bwd   N > 1024 fwd
+    acdc        yes         yes         yes           yes           factored (N % 128 == 0)
+    circulant   yes         yes         yes           yes           two-call
+    hadamard    yes         yes         yes           yes           two-call
+
+(DCT-II, real-DFT and pow2-N Walsh-Hadamard respectively.)  Above
+``MAX_FUSED_N`` every family's backward is the two-call
+``acdc_bwd_two_call``; only the DCT's forward has a factored kernel —
+the others would need factorizations of their own (Hadamard is a plain
+Kronecker product).  ``ops.ACDC_FWD_DISPATCHES`` counts the route each
+traced forward took.
 
 ``autotune.py`` keys its memo/persistent cache on
 ``(direction, n, k, dtype, bias, permute, family)`` so a block size

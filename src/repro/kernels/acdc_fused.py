@@ -18,8 +18,9 @@ Memory behaviour per grid step (row-block of ``bm`` rows):
 which is exactly the paper's "minimum 8N bytes moved per layer" once the
 transform matrix is amortized over a large batch.  Like the paper's fused
 kernel, this path is limited by on-chip memory: both C and C^T tiles must
-fit VMEM, so it is used for N <= ``MAX_FUSED_N`` and the two-call
-``scaled_matmul`` path covers larger sizes (ops.py picks automatically).
+fit VMEM, so it is used for N <= ``MAX_FUSED_N``; larger sizes take the
+factored-DCT kernel (``acdc_factored.py``) or the two-call
+``scaled_matmul`` path (ops.py picks automatically).
 """
 
 from __future__ import annotations

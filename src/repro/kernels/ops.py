@@ -4,7 +4,9 @@
 ``method='pallas'``:
 
 * N <= MAX_FUSED_N      -> single fused kernel (paper's "single call");
-* larger N              -> two chained ``scaled_matmul`` kernels with the
+* larger N, DCT family, -> the factored-DCT kernel (``acdc_factored.py``):
+  N % 128 == 0             one call, no N x N operand — the served path;
+* any other larger N    -> two chained ``scaled_matmul`` kernels with the
                            diagonals fused (paper's "multiple call");
 * custom VJP that RECOMPUTES the transform-domain intermediate ``h2`` in
   the backward pass instead of storing it — the paper's section 5.3
@@ -26,7 +28,8 @@ includes a (K-1)-deep activation stash) doesn't fit, the backward falls
 back to the per-layer HBM-remat scan; when the whole cascade exceeds
 the forward fused budget both directions fall back to the per-layer
 scan (each layer still fused forward + backward).  Routing decisions
-are counted in ``CASCADE_BWD_DISPATCHES`` for the bench/CI regression
+are counted in ``CASCADE_BWD_DISPATCHES`` (cascade backward) and
+``ACDC_FWD_DISPATCHES`` (per-layer forward) for the bench/CI regression
 gate.
 
 The backward formulas are the paper's eqs. (10)-(14):
@@ -58,6 +61,7 @@ from repro.core import transforms
 from repro.kernels import acdc_bwd as bwd_mod
 from repro.kernels import acdc_cascade_bwd as cascade_bwd_mod
 from repro.kernels import acdc_cascade_fused as cascade_mod
+from repro.kernels import acdc_factored as factored_mod
 from repro.kernels import acdc_fused as fused_mod
 from repro.kernels import autotune
 from repro.kernels import paged_attn as paged_attn_mod
@@ -87,6 +91,19 @@ CASCADE_BWD_DISPATCHES = CounterDict(
                      "trace-time cascade-backward routing decisions",
                      labels=("route",)),
     ("reverse_sweep", "per_layer_scan"))
+
+#: trace-time routing of the per-layer ACDC forward, same contract as
+#: ``CASCADE_BWD_DISPATCHES``: ``fused`` is the single-call kernel at N <=
+#: ``MAX_FUSED_N``; ``factored`` the factored-DCT kernel
+#: (``acdc_factored.py``) above it for the ``acdc`` family at N a multiple
+#: of 128; ``two_call`` the chained ``scaled_matmul`` kernels every other
+#: family and size takes above it.  Registry metric:
+#: ``kernel_acdc_fwd_dispatches_total{route=}``.
+ACDC_FWD_DISPATCHES = CounterDict(
+    REGISTRY.counter("kernel_acdc_fwd_dispatches_total",
+                     "trace-time per-layer ACDC forward routing decisions",
+                     labels=("route",)),
+    ("fused", "factored", "two_call"))
 
 #: trace-time routing of the paged-attention decode/verify step, same
 #: contract as ``CASCADE_BWD_DISPATCHES``: ``fused`` is the block-table
@@ -140,12 +157,21 @@ def _family_mats(family, n):
 
 def _acdc_fwd_impl(x2, a, d, bias, *, family="acdc", interpret):
     n = x2.shape[-1]
-    c, ct = _family_mats(family, n)
     if n <= fused_mod.MAX_FUSED_N:
+        ACDC_FWD_DISPATCHES["fused"] += 1
+        c, ct = _family_mats(family, n)
         bm = autotune.autotuned_bm("fwd", n, dtype=x2.dtype,
                                    bias=bias is not None, family=family)
         return fused_mod.acdc_fused_pallas(x2, a, d, bias, c, ct, bm=bm,
                                            interpret=interpret)
+    if family == "acdc" and factored_mod.supports(n):
+        # The DCT factored into VMEM-resident stages: no N x N operand.
+        ACDC_FWD_DISPATCHES["factored"] += 1
+        bm = factored_mod.pick_bm(x2.shape[0], n, x2.dtype.itemsize)
+        return factored_mod.acdc_factored_pallas(x2, a, d, bias, bm=bm,
+                                                 interpret=interpret)
+    ACDC_FWD_DISPATCHES["two_call"] += 1
+    c, ct = _family_mats(family, n)
     # Two-call path: h2 lands in HBM exactly once.  A and D are fused as
     # pre-scales; the bias-on-D commutes through the final matmul as
     # bias @ C^T (an O(N^2) one-off, amortized over the batch).

@@ -9,6 +9,9 @@ fully-fused kernel's VMEM budget is exceeded — the TPU analogue of the
 paper's cuFFT-based multiple-call implementation (section 5.2), but with
 the diagonal scalings folded in, so the intermediate ``h2`` round-trips HBM
 exactly once instead of three extra round trips for A, D and the bias.
+The DCT family's forward at N a multiple of 128 takes the factored kernel
+(``acdc_factored.py``) instead; these calls remain the forward of the
+other families and sizes above ``MAX_FUSED_N``, and the backward of all.
 """
 
 from __future__ import annotations

@@ -38,14 +38,20 @@ def test_acdc_fused_vs_oracle(m, n, dtype):
         atol=_tol(dtype) * np.sqrt(n), rtol=1e-2)
 
 
-def test_acdc_fused_two_call_path():
-    """N > MAX_FUSED_N exercises the chained scaled-matmul implementation."""
-    n = fused_mod.MAX_FUSED_N * 2
+@pytest.mark.parametrize("n,route", [
+    (fused_mod.MAX_FUSED_N * 2, "factored"),
+    (fused_mod.MAX_FUSED_N * 2 - 48, "two_call"),   # not a multiple of 128
+])
+def test_acdc_fused_two_call_path(n, route):
+    """N > MAX_FUSED_N: the factored-DCT kernel where N is a multiple of
+    128, the chained scaled-matmul implementation elsewhere."""
     x = jax.random.normal(jax.random.PRNGKey(0), (8, n))
     a = 1 + 0.1 * jax.random.normal(jax.random.PRNGKey(1), (n,))
     d = 1 + 0.1 * jax.random.normal(jax.random.PRNGKey(2), (n,))
     b = 0.1 * jax.random.normal(jax.random.PRNGKey(3), (n,))
+    before = ops.ACDC_FWD_DISPATCHES[route]
     got = ops.acdc_fused_op(x, a, d, b)
+    assert ops.ACDC_FWD_DISPATCHES[route] == before + 1
     want = ref.acdc_fused_ref(x, a, d, b)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-3, rtol=1e-3)

@@ -20,6 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import acdc_bwd
+from repro.kernels import acdc_factored
 from repro.kernels import acdc_cascade_bwd as cascade_bwd
 from repro.kernels import acdc_cascade_fused as cascade_fused
 from repro.kernels import paged_attn
@@ -84,12 +85,27 @@ def test_paged_attention_compiles(spec, t):
 @pytest.mark.parametrize("rows", [4, 1024], ids=["decode", "prefill"])
 @pytest.mark.parametrize("n", [2048, 6144])
 def test_scaled_matmul_compiles(spec, n, rows):
-    """The two-call ACDC forward above ``MAX_FUSED_N``: qwen3's attn_out
-    (2048) and padded-square mlp (6144) cascades."""
+    """The two-call kernels above ``MAX_FUSED_N`` at qwen3's attn_out
+    (2048) and padded-square mlp (6144) widths: the backward's building
+    block, and the forward of the families without a factored kernel."""
     compiled = _compile(
         lambda x, w, pre: scaled_matmul.scaled_matmul_pallas(x, w, pre=pre),
         spec((rows, n), jnp.bfloat16), spec((n, n), jnp.float32),
         spec((n,), jnp.float32))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows", [32, 1024], ids=["decode", "prefill"])
+@pytest.mark.parametrize("n", [2048, 6144])
+def test_factored_acdc_forward_compiles(spec, n, rows):
+    """The factored-DCT forward those cascades take instead, at its fixed
+    row block: bf16 activations, fp32 diagonals."""
+    bm = acdc_factored.pick_bm(rows, n, 2)
+    f32 = jnp.float32
+    compiled = _compile(
+        lambda x, a, d: acdc_factored.acdc_factored_pallas(x, a, d, None,
+                                                           bm=bm),
+        spec((rows, n), jnp.bfloat16), spec((n,), f32), spec((n,), f32))
     assert "tpu_custom_call" in compiled.as_text()
 
 
