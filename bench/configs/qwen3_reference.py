@@ -22,11 +22,17 @@ key/value head ``i // (Hq / Hkv)``), scale ``Dh**-0.5``; then
 Two departures from the Hugging Face module, both the program's
 conventions: RoPE rotates interleaved pairs ``(x[2i], x[2i+1])`` instead
 of the two halves (the same model up to a fixed permutation of the q/k
-head dims), and with ``sell = "acdc"`` the attention output and the three
-MLP projections are the paper's cascade: ``y = x . A1 C D1 C^T . P .
+head dims), and with ``sell_kind = "acdc"`` the attention output and the
+three MLP projections are the paper's cascade: ``y = x . A1 C D1 C^T . P .
 A2 C D2 C^T`` on the input zero-padded to ``N = max(in, out)`` rounded up
 to 128, truncated to ``out``; ``C`` the orthonormal DCT-II, ``P`` the
 riffle (perfect shuffle) between the K = 2 layers.
+
+``cfg`` is the configuration file's ``model`` block, keyed by the
+program's ``ModelConfig`` field names (``n_layers``, ``d_model``,
+``n_heads``, ``n_kv_heads``, ``head_dim``, ``d_ff``, ``rope_theta``,
+``norm_eps``, ``sell_kind``); a reference module gives ``transform_mats``
+and ``token_gaps`` (``bench/harness/spec.py``).
 
 ``quant="fp8"`` is the control: every projection, transform and the head
 take both operands rounded to float8 e4m3 (per-tensor absmax scale), the
@@ -146,7 +152,7 @@ def layer(lp, x, positions, cfg, mats, quant):
 
 def transform_mats(cfg) -> dict:
     """The DCT matrices the ACDC projections need, keyed by size."""
-    if cfg["sell"] != "acdc":
+    if cfg["sell_kind"] != "acdc":
         return {}
     d, hd = cfg["d_model"], cfg["n_heads"] * cfg["head_dim"]
     sizes = {op_size(hd, d), op_size(d, cfg["d_ff"]), op_size(cfg["d_ff"], d)}

@@ -1,16 +1,47 @@
 """Everything a run needs, found by name from ``BENCHMARK.json``.
 
 A cell names its configuration and its traffic mix; each lives in a file
-of its own under ``bench/``:
+of its own under ``bench/``, and a later cell, mix, metric or kernel is
+added as files and entries: no file already under ``bench/`` changes.
 
-* ``configs/<config>.json``: the model configuration as it is run, with
-  the name of its plain reference module beside it;
+``configs/<config>.json``, the configuration as it is run:
+
+* ``program``: ``arch``, the name ``launch/serve.py --arch`` takes;
+* ``model``: the ``ModelConfig`` fields (``models/common.py``) the
+  program must have, by their field names.  ``harness.program.check``
+  compares every field of the served configuration with it; a field the
+  block leaves out must hold its dataclass default, and only
+  ``program.EXEMPT`` may differ.  The reference and the work counts read
+  their sizes from it (``program.sizes``), and ``sell_init_std`` is the
+  sigma of the ACDC diagonals' init;
+* ``weights`` (optional): ``{path suffix: init kind}`` for leaves that
+  ``harness.weights.BUILT_IN`` does not cover, the kinds those of
+  ``weights.KINDS``;
+* ``serve``: the engine's shape (slots, pages, prompt and output limits,
+  the number of finished requests the check samples);
+* ``limits``: each number ``harness.check`` compares, with its limit;
+* ``rehearsal``: a ``model`` block at the registry's smoke sizes and the
+  ``serve`` keys that change, for ``--rehearse`` on the CPU;
+* ``reference``: the file name, beside the configuration, of its plain
+  reference, a module that imports nothing of the program and gives
+  ``transform_mats(cfg) -> mats`` (any constants it needs, made once a
+  run) and ``token_gaps(params, mats, tokens, rows, targets, cfg,
+  quant=None) -> {"gap", "rank"}`` (``harness.check.served_gaps``), where
+  ``cfg`` is the ``model`` block;
+* ``published``: the source's own ``config.json`` keys, for the record
+  (nothing checks against it), with ``reduced`` and ``assumed`` beside it.
+
+The rest, each found by its name:
+
 * ``traffic/<mix>.json``: the parameters the one generator reads;
 * ``metrics/<metric>.py``: a reader with ``read(run) -> float | None``
-  for each per-layer metric.
-
-A later cell, mix or metric is added as files and entries; no harness file
-changes.
+  for each per-layer metric;
+* ``kernels/<class>.json``: a kernel class, the regular expressions of
+  the custom-call names that belong to it (``harness.trace``);
+* ``work/<module>.py``: the operations and bytes a configuration's
+  layers require.  ``work/counts.py`` counts the layers of the Qwen3
+  configurations; a configuration with other layers brings a module of
+  its own beside it, read by metric files of its own.
 """
 
 from __future__ import annotations

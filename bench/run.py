@@ -70,7 +70,7 @@ def per_layer(cell, out: dict, sizes: dict, kind: str) -> dict:
     from harness import device
 
     run = {"trace": out["trace"], "records": out["records"], "sizes": sizes,
-           "elem": 2 if cell.config["program"]["dtype"] == "bfloat16" else 4,
+           "elem": 2 if sizes["dtype"] == "bfloat16" else 4,
            "peak": device.peaks(kind), "e2e": out["e2e"]}
     metrics = {}
     for m in cell.per_layer:

@@ -80,7 +80,7 @@ def main() -> None:
             "ttft_p50_s": stats.percentile(wm["ttft"], 50),
             "ttft_p90_s": stats.percentile(wm["ttft"], 90),
             "itl_p50_ms": 1e3 * stats.percentile(wm["gaps"], 50),
-            "itl_p95_ms": 1e3 * stats.percentile(wm["gaps"], 95),
+            "itl_p98_ms": 1e3 * stats.percentile(wm["gaps"], 98),
             "late_max_ms": 1e3 * max(wm["late"]),
             "drain_s": time.perf_counter() - t_drain,
             "device": devs[0].device_kind}), flush=True)

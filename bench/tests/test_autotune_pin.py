@@ -58,10 +58,10 @@ def test_committed_pins_run_as_pinned_in_every_serving_cell(pins):
         blk = paged_attn.decode_block(enc)
         for w in bench["workloads"]:
             cell = spec.load_cell(w["name"])
-            p = cell.config["published"]
-            assert p["head_dim"] == dh
+            m = cell.config["model"]
+            assert m["head_dim"] == dh
             assert paged_attn.clamp_block(
-                blk, hkv=p["num_key_value_heads"], dh=dh,
-                group=p["num_attention_heads"] // p["num_key_value_heads"],
+                blk, hkv=m["n_kv_heads"], dh=dh,
+                group=m["n_heads"] // m["n_kv_heads"],
                 t=t, bs=cell.config["serve"]["block_size"],
                 itemsize=2 if dtype == "bfloat16" else 4) == blk

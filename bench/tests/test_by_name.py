@@ -5,19 +5,35 @@ import json
 import shutil
 from pathlib import Path
 
-from harness import spec
+import pytest
+
+from harness import program, spec
 
 BENCH = Path(__file__).resolve().parents[1]
 
+#: a reference module of the least shape: the two functions a run calls
+STUB_REFERENCE = """
+def transform_mats(cfg):
+    return {}
 
-def test_new_cell_found_by_name(tmp_path):
+
+def token_gaps(params, mats, tokens, rows, targets, cfg, quant=None):
+    raise NotImplementedError
+"""
+
+
+@pytest.mark.parametrize("source", ["configs/qwen3_1_7b-dense.json",
+                                    "tests/data/deepseek_moe_16b.json"])
+def test_new_cell_found_by_name(tmp_path, source):
     root = tmp_path / "bench"
     for sub in ("configs", "traffic", "metrics"):
         (root / sub).mkdir(parents=True)
-    shutil.copy(BENCH / "configs" / "qwen3_reference.py",
-                root / "configs" / "qwen3_reference.py")
-    config = json.loads((BENCH / "configs" / "qwen3_1_7b-dense.json")
-                        .read_text())
+    config = json.loads((BENCH / source).read_text())
+    if (BENCH / "configs" / config["reference"]).is_file():
+        shutil.copy(BENCH / "configs" / config["reference"],
+                    root / "configs" / config["reference"])
+    else:
+        (root / "configs" / config["reference"]).write_text(STUB_REFERENCE)
     config["name"] = "new_model"
     (root / "configs" / "new_model.json").write_text(json.dumps(config))
     (root / "traffic" / "bursty.json").write_text(json.dumps(
@@ -45,6 +61,8 @@ def test_new_cell_found_by_name(tmp_path):
     assert [m["name"] for m in cell.per_layer] == ["new_metric"]
     assert cell.reader("new_metric")({"records": {"x": 21}}) == 42
     assert hasattr(cell.reference(), "token_gaps")
+    # the program is held to the new file's model block as it stands
+    program.serve_model(cell.config, rehearse=True)
 
 
 def test_every_listed_metric_has_a_reader_and_every_cell_its_files():

@@ -7,7 +7,7 @@ import pytest
 from work import counts
 
 QWEN3 = {"n_layers": 28, "d_model": 2048, "n_heads": 16, "n_kv_heads": 8,
-         "head_dim": 128, "d_ff": 6144, "vocab": 151936, "sell": "acdc"}
+         "head_dim": 128, "d_ff": 6144, "vocab_size": 151936, "sell_kind": "acdc"}
 
 
 def test_dense_projection():
@@ -38,7 +38,7 @@ def test_sell_work_covers_the_four_targets_of_every_layer():
     per_row = (counts.proj_flops(2048, 2048, True)
                + 3 * counts.proj_flops(2048, 6144, True))
     assert flops == pytest.approx(28 * per_row)
-    dense = dict(QWEN3, sell="dense")
+    dense = dict(QWEN3, sell_kind="dense")
     assert counts.sell_work(dense, 1, 1, 2) == (0.0, 0.0)
 
 
@@ -50,7 +50,7 @@ def test_attention_by_context():
 
 
 def test_token_flops():
-    dense = dict(QWEN3, sell="dense")
+    dense = dict(QWEN3, sell_kind="dense")
     per_layer = 2 * 2048 * (2048 + 1024 + 1024 + 2048 + 3 * 6144)
     head = 2 * 2048 * 151936
     ctx = 10

@@ -84,7 +84,7 @@ def test_fp8_control_is_not_correct(cell_name):
     for seed in (11, 12, 13):
         params = weights.make_init(abstract, 0.061)(weights.seed_words(seed))
         rng = np.random.default_rng(seed)
-        tokens = jnp.asarray(rng.integers(0, sizes["vocab"], 320), jnp.int32)
+        tokens = jnp.asarray(rng.integers(0, sizes["vocab_size"], 320), jnp.int32)
         rows = jnp.arange(63, 319, dtype=jnp.int32)
         got = check.gap_stats({k: np.asarray(v) for k, v in ref.token_gaps(
             params, mats, tokens, rows, tokens[64:320], sizes,

@@ -16,7 +16,8 @@ faster kernel raises its share and never its count:
   ``2 * c * Hkv * Dh`` cached elements;
 * the LM head is counted only for rows whose logits are used.
 
-``sizes`` is the dictionary ``harness.program.sizes`` gives.
+``sizes`` is the configuration file's ``model`` block
+(``harness.program.sizes``), keyed by ``ModelConfig``'s field names.
 """
 
 from __future__ import annotations
@@ -34,7 +35,7 @@ def projections(sizes: dict):
     d, f = sizes["d_model"], sizes["d_ff"]
     hq = sizes["n_heads"] * sizes["head_dim"]
     hk = sizes["n_kv_heads"] * sizes["head_dim"]
-    sell = sizes["sell"] == "acdc"
+    sell = sizes["sell_kind"] == "acdc"
     return [("wq", d, hq, False), ("wk", d, hk, False), ("wv", d, hk, False),
             ("wo", hq, d, sell), ("wg", d, f, sell), ("wu", d, f, sell),
             ("wd", f, d, sell)]
@@ -93,4 +94,4 @@ def token_flops(sizes: dict, context: int) -> float:
     per_layer = sum(proj_flops(i, o, s) for _, i, o, s in projections(sizes))
     per_layer += attn_flops(sizes, context)
     return (sizes["n_layers"] * per_layer
-            + 2.0 * sizes["d_model"] * sizes["vocab"])
+            + 2.0 * sizes["d_model"] * sizes["vocab_size"])
