@@ -18,7 +18,11 @@ the bytes it needs.  This kernel (vLLM-style) never builds that view:
   in-kernel DMA on the input/output-aliased pool, then attended straight
   from VMEM (so the streamed prefix never needs a read-after-write of
   the pool).  Parked/stalled rows and positions at/beyond the virtual
-  row route to the trash page exactly like the gather path's scatter.
+  row route to the trash page exactly like the gather path's scatter;
+* the pool is the whole stacked ``(L, NB+1, bs, Hkv, Dh)`` cache and
+  the layer one more scalar-prefetch operand, so a layer loop carries
+  the aliased pool and writes only the new rows: no layer's pages are
+  sliced out of the cache or stacked back into it.
 
 Per slot per layer the streamed bytes are ``ceil(len/bs) * bs * bh-slice
 * Dh * 2 * itemsize`` — O(len), independent of the table capacity MB —
@@ -141,7 +145,7 @@ def clamp_block(blk: Tuple[int, int], *, hkv: int, dh: int, group: int,
 
 
 def _kernel(virtual, t, bs, pc, bh, group, dh, softcap,
-            routed_r, pos_r, start_r, nch_r, phys_r, off_r, win_r,
+            routed_r, pos_r, start_r, nch_r, phys_r, off_r, win_r, layer_r,
             q_ref, kn_ref, vn_ref, kp_hbm, vp_hbm,
             o_ref, kp_out, vp_out, kbuf, vbuf, sem_k, sem_v, sem_s):
     # Mosaic lowers 2-D matmuls, reductions and masks; every per-head
@@ -150,17 +154,21 @@ def _kernel(virtual, t, bs, pc, bh, group, dh, softcap,
     i = pl.program_id(0)
     h0 = pl.program_id(1) * bh
     tg = t * group
+    lay = layer_r[0]
 
     # -- 1. persist the T new tokens' K/V head-slice into their (already
-    #    trash-routed) tail pages.  Disjoint from every streamed read
-    #    (reads stop at kpos < position), so no ordering hazard.
+    #    trash-routed) tail pages of layer ``lay``.  Disjoint from every
+    #    streamed read (reads stop at kpos < position), so no ordering
+    #    hazard.
     for tt in range(t):
         page = phys_r[i, tt]
         o = off_r[i, tt]
         ck = pltpu.make_async_copy(
-            kn_ref.at[0, tt], kp_out.at[page, o, pl.ds(h0, bh)], sem_s.at[0])
+            kn_ref.at[0, tt], kp_out.at[lay, page, o, pl.ds(h0, bh)],
+            sem_s.at[0])
         cv = pltpu.make_async_copy(
-            vn_ref.at[0, tt], vp_out.at[page, o, pl.ds(h0, bh)], sem_s.at[1])
+            vn_ref.at[0, tt], vp_out.at[lay, page, o, pl.ds(h0, bh)],
+            sem_s.at[1])
         ck.start()
         cv.start()
         ck.wait()
@@ -204,15 +212,15 @@ def _kernel(virtual, t, bs, pc, bh, group, dh, softcap,
         base = ci * pc
         for jj in range(pc):                           # static unroll
             page = routed_r[i, base + jj]
-            pltpu.make_async_copy(kp_hbm.at[page, :, pl.ds(h0, bh)],
+            pltpu.make_async_copy(kp_hbm.at[lay, page, :, pl.ds(h0, bh)],
                                   kbuf.at[jj], sem_k.at[jj]).start()
-            pltpu.make_async_copy(vp_hbm.at[page, :, pl.ds(h0, bh)],
+            pltpu.make_async_copy(vp_hbm.at[lay, page, :, pl.ds(h0, bh)],
                                   vbuf.at[jj], sem_v.at[jj]).start()
         for jj in range(pc):
             page = routed_r[i, base + jj]
-            pltpu.make_async_copy(kp_hbm.at[page, :, pl.ds(h0, bh)],
+            pltpu.make_async_copy(kp_hbm.at[lay, page, :, pl.ds(h0, bh)],
                                   kbuf.at[jj], sem_k.at[jj]).wait()
-            pltpu.make_async_copy(vp_hbm.at[page, :, pl.ds(h0, bh)],
+            pltpu.make_async_copy(vp_hbm.at[lay, page, :, pl.ds(h0, bh)],
                                   vbuf.at[jj], sem_v.at[jj]).wait()
         shape = (tg, pc * bs)
         kpos = base * bs + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
@@ -247,11 +255,12 @@ def paged_attention(
     q: jax.Array,                   # (B, T, Hq, Dh) post-RoPE queries
     knew: jax.Array,                # (B, T, Hkv, Dh) post-RoPE new keys
     vnew: jax.Array,                # (B, T, Hkv, Dh) new values
-    k_pages: jax.Array,             # (NB+1, bs, Hkv, Dh) this layer's pool
+    k_pages: jax.Array,             # ([L,] NB+1, bs, Hkv, Dh) page pool
     v_pages: jax.Array,
     block_tables: jax.Array,        # (B, MB) int32, -1 = unmapped
     position: jax.Array,            # (B,) first write index per row
     window: jax.Array,              # traced int32 scalar, 0 = global
+    layer: Optional[jax.Array] = None,  # traced int32 scalar
     *,
     softcap: float,
     page_chunk: int,
@@ -263,11 +272,23 @@ def paged_attention(
     Returns ``(out (B, T, Hq, Dh), k_pages, v_pages)`` with the T new
     tokens' K/V scattered into the (aliased, in-place) pools — drop-in
     for the scatter+gather+SDPA sequence in ``models/attention.py``.
+
+    With ``layer`` the pools are the whole stacked ``(L, NB+1, bs, Hkv,
+    Dh)`` cache and the kernel reads and writes only that layer's pages
+    of it, so a layer loop can carry the pools without slicing one out;
+    without it they are one layer's ``(NB+1, bs, Hkv, Dh)``.
     """
+    if layer is None:
+        out, kp, vp = paged_attention(
+            q, knew, vnew, k_pages[None], v_pages[None], block_tables,
+            position, window, jnp.int32(0), softcap=softcap,
+            page_chunk=page_chunk, head_block=head_block,
+            interpret=interpret)
+        return out, kp[0], vp[0]
     b, t, hq, dh = q.shape
     hkv = knew.shape[2]
     group = hq // hkv
-    n_pages, bs = k_pages.shape[0], k_pages.shape[1]
+    n_pages, bs = k_pages.shape[1], k_pages.shape[2]
     mb = block_tables.shape[1]
     virtual = mb * bs
     pc, bh = page_chunk, head_block
@@ -288,6 +309,7 @@ def paged_attention(
     phys = jnp.where(writable, phys, n_pages - 1).astype(jnp.int32)
     off = (qpos % bs).astype(jnp.int32)
     win = jnp.reshape(window, (1,)).astype(jnp.int32)
+    lay = jnp.reshape(layer, (1,)).astype(jnp.int32)
     span = bs * pc
     frontier = jnp.minimum(pos, virtual)
     start = jnp.where(win[0] > 0,
@@ -308,7 +330,7 @@ def paged_attention(
     # blocks keep their leading unit dim (a squeezed one would make the
     # Mosaic verifier reject the tail-page DMA's slice of the block)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
+        num_scalar_prefetch=8,
         grid=(b, hkv // bh),
         in_specs=[
             pl.BlockSpec((1, bh, tg, dh), lambda i, j, *_: (i, j, 0, 0)),
@@ -338,11 +360,11 @@ def paged_attention(
             jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
             jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
         ],
-        # operand indices count the 7 scalar-prefetch args: the pools are
-        # operands 10/11 and alias outputs 1/2 (in-place update)
-        input_output_aliases={10: 1, 11: 2},
+        # operand indices count the 8 scalar-prefetch args: the pools are
+        # operands 11/12 and alias outputs 1/2 (in-place update)
+        input_output_aliases={11: 1, 12: 2},
         interpret=interpret,
-    )(routed, pos, start, nch, phys, off, win,
+    )(routed, pos, start, nch, phys, off, win, lay,
       qh, knew.astype(k_pages.dtype), vnew.astype(v_pages.dtype),
       k_pages, v_pages)
     out = out.reshape(b, hkv, t, group, dh).transpose(0, 2, 1, 3, 4)

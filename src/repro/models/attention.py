@@ -273,19 +273,23 @@ def scatter_prefill_pages(
 def _attention_paged(
     params: dict,
     x: jax.Array,                   # (B, T, D); T=1 decode, T=k+1 verify
-    k_pages: jax.Array,             # (NB+1, bs, Hkv, Dh) — this layer's pool
+    k_pages: jax.Array,             # (L, NB+1, bs, Hkv, Dh) — every layer
     v_pages: jax.Array,
     block_tables: jax.Array,        # (B, MB) int32, -1 = unmapped
     position: jax.Array,            # (B,) first write index per row
     window: jax.Array,
+    layer: jax.Array,               # traced int32 scalar: this layer's pool
     cfg: ModelConfig,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Shared paged decode/verify body — decode is the T=1 case.
 
-    The T new tokens' K/V is set-scattered into their tail pages
-    (``block_tables[b, pos // bs]``, offset ``pos % bs``); tokens whose
-    page is unmapped or whose position is at/beyond the virtual row
-    length (parked/stalled slots) write to the trash page instead.
+    The T new tokens' K/V is set-scattered into their tail pages of pool
+    ``layer`` (``block_tables[b, pos // bs]``, offset ``pos % bs``);
+    tokens whose page is unmapped or whose position is at/beyond the
+    virtual row length (parked/stalled slots) write to the trash page
+    instead.  The whole stacked pools come in and go out, so a layer
+    loop carries them and only the new rows are written: no layer's
+    pool is sliced out or stacked back.
 
     Attention dispatches through ``ops.paged_attn_route`` (the single
     call site for both grid shapes): in budget on a real device — or
@@ -298,7 +302,7 @@ def _attention_paged(
     are identical either way (pinned by tests/test_paged_attention.py).
     """
     b, t, _ = x.shape
-    n_pages, bs = k_pages.shape[0], k_pages.shape[1]
+    n_pages, bs = k_pages.shape[1], k_pages.shape[2]
     mb = block_tables.shape[1]
     virtual = mb * bs
     dh = cfg.head_dim_
@@ -314,20 +318,20 @@ def _attention_paged(
         pc, bh = blk
         out, k_pages, v_pages = paged_attn_mod.paged_attention(
             q, k, v, k_pages, v_pages, block_tables, position, window,
-            softcap=cfg.attn_logit_softcap, page_chunk=pc, head_block=bh,
-            interpret=ops.interpret_mode())
+            layer, softcap=cfg.attn_logit_softcap, page_chunk=pc,
+            head_block=bh, interpret=ops.interpret_mode())
     else:
         blk_idx = jnp.minimum(pos // bs, mb - 1)                       # (B,T)
         phys = jnp.take_along_axis(block_tables, blk_idx, axis=1)      # (B,T)
         writable = jnp.logical_and(phys >= 0, pos < virtual)
         phys = jnp.where(writable, phys, n_pages - 1)                  # sink
         off = pos % bs
-        k_pages = k_pages.at[phys, off].set(k.astype(k_pages.dtype))
-        v_pages = v_pages.at[phys, off].set(v.astype(v_pages.dtype))
+        k_pages = k_pages.at[layer, phys, off].set(k.astype(k_pages.dtype))
+        v_pages = v_pages.at[layer, phys, off].set(v.astype(v_pages.dtype))
 
         tbl = jnp.where(block_tables >= 0, block_tables, 0)            # (B,MB)
-        ck = k_pages[tbl].reshape(b, virtual, *k_pages.shape[2:])
-        cv = v_pages[tbl].reshape(b, virtual, *v_pages.shape[2:])
+        ck = k_pages[layer, tbl].reshape(b, virtual, *k_pages.shape[3:])
+        cv = v_pages[layer, tbl].reshape(b, virtual, *v_pages.shape[3:])
         k_pos = jnp.arange(virtual, dtype=jnp.int32)[None, :]
         mask = causal_window_mask(pos, k_pos, window)                  # (B,T,V)
         out = _sdpa(q, ck, cv, mask, cfg)
@@ -340,17 +344,18 @@ def _attention_paged(
 def attention_decode_paged(
     params: dict,
     x: jax.Array,                   # (B, 1, D)
-    k_pages: jax.Array,             # (NB+1, bs, Hkv, Dh) — this layer's pool
+    k_pages: jax.Array,             # (L, NB+1, bs, Hkv, Dh) — every layer
     v_pages: jax.Array,
     block_tables: jax.Array,        # (B, MB) int32, -1 = unmapped
     position: jax.Array,            # (B,) current index
     window: jax.Array,
+    layer: jax.Array,               # traced int32 scalar
     cfg: ModelConfig,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Paged twin of :func:`attention_decode`: the T=1 grid shape of
     :func:`_attention_paged`."""
     return _attention_paged(params, x, k_pages, v_pages, block_tables,
-                            position, window, cfg)
+                            position, window, layer, cfg)
 
 
 def attention_verify(
@@ -396,11 +401,12 @@ def attention_verify(
 def attention_verify_paged(
     params: dict,
     x: jax.Array,                   # (B, T, D)
-    k_pages: jax.Array,             # (NB+1, bs, Hkv, Dh) — this layer's pool
+    k_pages: jax.Array,             # (L, NB+1, bs, Hkv, Dh) — every layer
     v_pages: jax.Array,
     block_tables: jax.Array,        # (B, MB) int32, -1 = unmapped
     position: jax.Array,            # (B,) first write index per row
     window: jax.Array,
+    layer: jax.Array,               # traced int32 scalar
     cfg: ModelConfig,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Paged twin of :func:`attention_verify`: the T=k+1 grid shape of
@@ -413,7 +419,7 @@ def attention_verify_paged(
     exactly like the single-token decode path.
     """
     return _attention_paged(params, x, k_pages, v_pages, block_tables,
-                            position, window, cfg)
+                            position, window, layer, cfg)
 
 
 def attention_decode(

@@ -321,6 +321,38 @@ def verify_step(
     return logits, {**cache, "k": nk, "v": nv}, None
 
 
+def _paged_layers(params: dict, x: jax.Array, cache: dict,
+                  block_tables: jax.Array, position: jax.Array, attend,
+                  cfg: ModelConfig):
+    """The decoder scan of the paged decode and verify steps: the stacked
+    self-attention pools ride in the carry and each layer's ``attend``
+    writes only its new rows into them, by layer index; the precomputed
+    cross KV rides along dense and untouched as ``xs``."""
+    window = jnp.zeros((), jnp.int32)
+    layers = jnp.arange(cache["xk"].shape[0], dtype=jnp.int32)
+
+    def body(carry, xs):
+        x, kp, vp = carry
+        layer, xk, xv, idx = xs
+        h = rms_norm(x, layer["norm1"]["scale"], cfg.norm_eps)
+        out, kp, vp = attend(layer["attn"], h, kp, vp, block_tables,
+                             position, window, idx, cfg)
+        x = x + out
+        # cross-attention against the precomputed encoder KV
+        h = rms_norm(x, layer["norm_x"]["scale"], cfg.norm_eps)
+        x = x + _cross_attend(layer, h, xk, xv, cfg)
+        h = rms_norm(x, layer["norm2"]["scale"], cfg.norm_eps)
+        x = x + mlp_mod.mlp(layer["mlp"], h, cfg)
+        return (x, kp, vp), None
+
+    (x, nk, nv), _ = jax.lax.scan(
+        body, (x, cache["k_pages"], cache["v_pages"]),
+        (params["decoder"], cache["xk"], cache["xv"], layers),
+        unroll=cfg.scan_unroll)
+    x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
+    return x, {**cache, "k_pages": nk, "v_pages": nv}
+
+
 def verify_step_paged(
     params: dict,
     cache: dict,
@@ -330,31 +362,10 @@ def verify_step_paged(
     cfg: ModelConfig,
 ):
     """Paged twin of :func:`verify_step`; cross KV stays dense."""
-    dtype = cfg.compute_dtype
-    x = embed_lookup(params["embed"], tokens, dtype)
-    window = jnp.zeros((), jnp.int32)
-
-    def body(carry, xs):
-        x = carry
-        layer, kp, vp, xk, xv = xs
-        h = rms_norm(x, layer["norm1"]["scale"], cfg.norm_eps)
-        out, kp, vp = attn_mod.attention_verify_paged(
-            layer["attn"], h, kp, vp, block_tables, position, window, cfg)
-        x = x + out
-        h = rms_norm(x, layer["norm_x"]["scale"], cfg.norm_eps)
-        x = x + _cross_attend(layer, h, xk, xv, cfg)
-        h = rms_norm(x, layer["norm2"]["scale"], cfg.norm_eps)
-        x = x + mlp_mod.mlp(layer["mlp"], h, cfg)
-        return x, (kp, vp)
-
-    x, (nk, nv) = jax.lax.scan(
-        body, x,
-        (params["decoder"], cache["k_pages"], cache["v_pages"],
-         cache["xk"], cache["xv"]),
-        unroll=cfg.scan_unroll)
-    x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
-    logits = unembed(params["embed"], x)
-    return logits, {**cache, "k_pages": nk, "v_pages": nv}, None
+    x = embed_lookup(params["embed"], tokens, cfg.compute_dtype)
+    x, cache = _paged_layers(params, x, cache, block_tables, position,
+                             attn_mod.attention_verify_paged, cfg)
+    return unembed(params["embed"], x), cache, None
 
 
 def decode_step_paged(params: dict, cache: dict, tokens: jax.Array,
@@ -362,29 +373,7 @@ def decode_step_paged(params: dict, cache: dict, tokens: jax.Array,
                       cfg: ModelConfig):
     """Mirror of :func:`decode_step` with self-attention KV paged; the
     precomputed cross KV rides along dense and untouched."""
-    dtype = cfg.compute_dtype
-    x = embed_lookup(params["embed"], tokens[:, None], dtype)
-    window = jnp.zeros((), jnp.int32)
-
-    def body(carry, xs):
-        x = carry
-        layer, kp, vp, xk, xv = xs
-        h = rms_norm(x, layer["norm1"]["scale"], cfg.norm_eps)
-        out, kp, vp = attn_mod.attention_decode_paged(
-            layer["attn"], h, kp, vp, block_tables, position, window, cfg)
-        x = x + out
-        # cross-attention against the precomputed encoder KV
-        h = rms_norm(x, layer["norm_x"]["scale"], cfg.norm_eps)
-        x = x + _cross_attend(layer, h, xk, xv, cfg)
-        h = rms_norm(x, layer["norm2"]["scale"], cfg.norm_eps)
-        x = x + mlp_mod.mlp(layer["mlp"], h, cfg)
-        return x, (kp, vp)
-
-    x, (nk, nv) = jax.lax.scan(
-        body, x,
-        (params["decoder"], cache["k_pages"], cache["v_pages"],
-         cache["xk"], cache["xv"]),
-        unroll=cfg.scan_unroll)
-    x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
-    logits = unembed(params["embed"], x)[:, 0]
-    return logits, {**cache, "k_pages": nk, "v_pages": nv}
+    x = embed_lookup(params["embed"], tokens[:, None], cfg.compute_dtype)
+    x, cache = _paged_layers(params, x, cache, block_tables, position,
+                             attn_mod.attention_decode_paged, cfg)
+    return unembed(params["embed"], x)[:, 0], cache

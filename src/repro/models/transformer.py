@@ -277,6 +277,40 @@ def verify_step(
     return logits, {"k": new_k, "v": new_v}, None
 
 
+def _paged_layers(params: dict, x: jax.Array, cache: dict,
+                  block_tables: jax.Array, position: jax.Array, attend,
+                  cfg: ModelConfig) -> Tuple[jax.Array, dict]:
+    """The layer scan of the paged decode and verify steps.
+
+    The whole stacked pools ride in the carry and each layer's attention
+    (``attend``, the decode or verify grid) addresses its own pool by
+    index, so the donated pools are updated in place: only the new rows
+    are written, and no layer's pool is sliced out or stacked back.
+    """
+    windows = jnp.asarray(cfg.layer_windows())
+    layers = jnp.arange(windows.shape[0], dtype=jnp.int32)
+
+    def body(carry, xs):
+        x, kp, vp = carry
+        layer, window, idx = xs
+        h = rms_norm(x, layer["norm1"]["scale"], cfg.norm_eps)
+        out, kp, vp = attend(layer["attn"], h, kp, vp, block_tables,
+                             position, window, idx, cfg)
+        x = x + out
+        h = rms_norm(x, layer["norm2"]["scale"], cfg.norm_eps)
+        if "moe" in layer:
+            x = x + mlp_mod.moe(layer["moe"], h, cfg)
+        else:
+            x = x + mlp_mod.mlp(layer["mlp"], h, cfg)
+        return (x, kp, vp), None
+
+    (x, new_k, new_v), _ = jax.lax.scan(
+        body, (x, cache["k_pages"], cache["v_pages"]),
+        (params["layers"], windows, layers), unroll=cfg.scan_unroll)
+    x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
+    return x, {"k_pages": new_k, "v_pages": new_v}
+
+
 def verify_step_paged(
     params: dict,
     cache: dict,
@@ -286,31 +320,10 @@ def verify_step_paged(
     cfg: ModelConfig,
 ) -> Tuple[jax.Array, dict, None]:
     """Paged twin of :func:`verify_step` (writes through the block table)."""
-    dtype = cfg.compute_dtype
-    x = embed_lookup(params["embed"], tokens, dtype)          # (B,T,D)
-    windows = jnp.asarray(cfg.layer_windows())
-
-    def body(carry, xs):
-        x = carry
-        layer, window, kp, vp = xs
-        h = rms_norm(x, layer["norm1"]["scale"], cfg.norm_eps)
-        out, kp, vp = attn_mod.attention_verify_paged(
-            layer["attn"], h, kp, vp, block_tables, position, window, cfg)
-        x = x + out
-        h = rms_norm(x, layer["norm2"]["scale"], cfg.norm_eps)
-        if "moe" in layer:
-            x = x + mlp_mod.moe(layer["moe"], h, cfg)
-        else:
-            x = x + mlp_mod.mlp(layer["mlp"], h, cfg)
-        return x, (kp, vp)
-
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["layers"], windows, cache["k_pages"],
-                  cache["v_pages"]),
-        unroll=cfg.scan_unroll)
-    x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
-    logits = unembed(params["embed"], x)
-    return logits, {"k_pages": new_k, "v_pages": new_v}, None
+    x = embed_lookup(params["embed"], tokens, cfg.compute_dtype)  # (B,T,D)
+    x, cache = _paged_layers(params, x, cache, block_tables, position,
+                             attn_mod.attention_verify_paged, cfg)
+    return unembed(params["embed"], x), cache, None
 
 
 def decode_step_paged(
@@ -322,28 +335,8 @@ def decode_step_paged(
     cfg: ModelConfig,
 ) -> Tuple[jax.Array, dict]:
     """One decode step against the paged KV pool -> (logits (B, V), cache)."""
-    dtype = cfg.compute_dtype
-    x = embed_lookup(params["embed"], tokens[:, None], dtype)  # (B,1,D)
-    windows = jnp.asarray(cfg.layer_windows())
-
-    def body(carry, xs):
-        x = carry
-        layer, window, kp, vp = xs
-        h = rms_norm(x, layer["norm1"]["scale"], cfg.norm_eps)
-        out, kp, vp = attn_mod.attention_decode_paged(
-            layer["attn"], h, kp, vp, block_tables, position, window, cfg)
-        x = x + out
-        h = rms_norm(x, layer["norm2"]["scale"], cfg.norm_eps)
-        if "moe" in layer:
-            x = x + mlp_mod.moe(layer["moe"], h, cfg)
-        else:
-            x = x + mlp_mod.mlp(layer["mlp"], h, cfg)
-        return x, (kp, vp)
-
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["layers"], windows, cache["k_pages"],
-                  cache["v_pages"]),
-        unroll=cfg.scan_unroll)
-    x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
-    logits = unembed(params["embed"], x)[:, 0]
-    return logits, {"k_pages": new_k, "v_pages": new_v}
+    x = embed_lookup(params["embed"], tokens[:, None],
+                     cfg.compute_dtype)                       # (B,1,D)
+    x, cache = _paged_layers(params, x, cache, block_tables, position,
+                             attn_mod.attention_decode_paged, cfg)
+    return unembed(params["embed"], x)[:, 0], cache

@@ -256,12 +256,14 @@ def decode_step(params: dict, cache: dict, tokens: jax.Array,
     }
 
 
-def _verify_impl(params, cache, tokens, position, cfg, attend):
+def _verify_impl(params, cache, tokens, position, cfg, attend, kv):
     """Shared speculative append-and-score body (dense / paged shared
-    attention differ only in ``attend``).  Returns ``(logits (B,T,V),
-    kv_leaves, states)`` with ``states`` the per-position recurrent
-    snapshots: each leaf is the cache leaf with a ``T+1`` time axis after
-    the batch axis (index j = state after j consumed tokens)."""
+    attention differ only in ``attend(app, a, kv) -> (out, kv)``, which
+    writes application ``app``'s K/V into the stacked pair ``kv``).
+    Returns ``(logits (B,T,V), kv, states)`` with ``states`` the
+    per-position recurrent snapshots: each leaf is the cache leaf with a
+    ``T+1`` time axis after the batch axis (index j = state after j
+    consumed tokens)."""
     dtype = cfg.compute_dtype
     emb = embed_lookup(params["embed"], tokens, dtype)          # (B,T,D)
     d = cfg.d_model
@@ -276,7 +278,7 @@ def _verify_impl(params, cache, tokens, position, cfg, attend):
 
     x = emb
     start = 0
-    new_ssm, new_conv, new_k, new_v = [], [], [], []
+    new_ssm, new_conv = [], []
     for app, size in enumerate(_n_groups(cfg)):
         sl = lambda p: p[start : start + size]
         group = (jax.tree.map(sl, params["layers"]),
@@ -291,20 +293,18 @@ def _verify_impl(params, cache, tokens, position, cfg, attend):
                                 jnp.concatenate([x, emb], axis=-1),
                                 2 * d, d, cfg, "shared_in")
         a = rms_norm(h, params["shared"]["norm1"]["scale"], cfg.norm_eps)
-        out, ck, cv = attend(app, a)
+        out, kv = attend(app, a, kv)
         h = h + out
         m = rms_norm(h, params["shared"]["norm2"]["scale"], cfg.norm_eps)
         h = h + mlp_mod.mlp(params["shared"]["mlp"], m, cfg)
         x = x + h
-        new_k.append(ck)
-        new_v.append(cv)
         start += size
 
     x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
     logits = unembed(params["embed"], x)
     states = {"ssm": jnp.concatenate(new_ssm, axis=0),          # (L,B,T+1,..)
               "conv": jnp.concatenate(new_conv, axis=0)}
-    return logits, (jnp.stack(new_k, axis=0), jnp.stack(new_v, axis=0)), states
+    return logits, kv, states
 
 
 def verify_step(
@@ -319,13 +319,15 @@ def verify_step(
     snapshotted per position in ``states`` for accepted-length commit."""
     window = jnp.zeros((), jnp.int32)
 
-    def attend(app, a):
-        return attn_mod.attention_verify(
-            params["shared"]["attn"], a, cache["attn_k"][app],
-            cache["attn_v"][app], position, window, cfg)
+    def attend(app, a, kv):
+        out, ck, cv = attn_mod.attention_verify(
+            params["shared"]["attn"], a, kv[0][app], kv[1][app], position,
+            window, cfg)
+        return out, (kv[0].at[app].set(ck), kv[1].at[app].set(cv))
 
-    logits, (nk, nv), states = _verify_impl(params, cache, tokens, position,
-                                            cfg, attend)
+    logits, (nk, nv), states = _verify_impl(
+        params, cache, tokens, position, cfg, attend,
+        (cache["attn_k"], cache["attn_v"]))
     new_cache = {"ssm": states["ssm"][:, :, -1],
                  "conv": states["conv"][:, :, -1],
                  "attn_k": nk, "attn_v": nv}
@@ -344,13 +346,15 @@ def verify_step_paged(
     through the block table; SSM/conv snapshots identical to dense."""
     window = jnp.zeros((), jnp.int32)
 
-    def attend(app, a):
-        return attn_mod.attention_verify_paged(
-            params["shared"]["attn"], a, cache["attn_k_pages"][app],
-            cache["attn_v_pages"][app], block_tables, position, window, cfg)
+    def attend(app, a, kv):
+        out, kp, vp = attn_mod.attention_verify_paged(
+            params["shared"]["attn"], a, *kv, block_tables, position,
+            window, jnp.int32(app), cfg)
+        return out, (kp, vp)
 
-    logits, (nk, nv), states = _verify_impl(params, cache, tokens, position,
-                                            cfg, attend)
+    logits, (nk, nv), states = _verify_impl(
+        params, cache, tokens, position, cfg, attend,
+        (cache["attn_k_pages"], cache["attn_v_pages"]))
     new_cache = {"ssm": states["ssm"][:, :, -1],
                  "conv": states["conv"][:, :, -1],
                  "attn_k_pages": nk, "attn_v_pages": nv}
@@ -381,7 +385,8 @@ def decode_step_paged(params: dict, cache: dict, tokens: jax.Array,
 
     x = emb
     start = 0
-    new_ssm, new_conv, new_k, new_v = [], [], [], []
+    new_ssm, new_conv = [], []
+    kp, vp = cache["attn_k_pages"], cache["attn_v_pages"]
     window = jnp.zeros((), jnp.int32)
     for app, size in enumerate(_n_groups(cfg)):
         sl = lambda p: p[start : start + size]
@@ -398,27 +403,25 @@ def decode_step_paged(params: dict, cache: dict, tokens: jax.Array,
                                 jnp.concatenate([x, emb], axis=-1),
                                 2 * d, d, cfg, "shared_in")
         a = rms_norm(h, params["shared"]["norm1"]["scale"], cfg.norm_eps)
+        # every application writes its new rows into the whole pools
         out, kp, vp = attn_mod.attention_decode_paged(
-            params["shared"]["attn"], a,
-            cache["attn_k_pages"][app], cache["attn_v_pages"][app],
-            block_tables, position, window, cfg)
+            params["shared"]["attn"], a, kp, vp, block_tables, position,
+            window, jnp.int32(app), cfg)
         h = h + out
         m = rms_norm(h, params["shared"]["norm2"]["scale"], cfg.norm_eps)
         h = h + mlp_mod.mlp(params["shared"]["mlp"], m, cfg)
         x = x + h
-        new_k.append(kp)
-        new_v.append(vp)
         start += size
 
     x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
     logits = unembed(params["embed"], x)[:, 0]
-    bs = cache["attn_k_pages"].shape[2]
+    bs = kp.shape[2]
     parked = (position >= block_tables.shape[1] * bs)[None, :]  # (1, B)
     ssm = jnp.concatenate(new_ssm, axis=0)
     conv = jnp.concatenate(new_conv, axis=0)
     return logits, {
         "ssm": jnp.where(parked[..., None, None, None], cache["ssm"], ssm),
         "conv": jnp.where(parked[..., None, None], cache["conv"], conv),
-        "attn_k_pages": jnp.stack(new_k, axis=0),
-        "attn_v_pages": jnp.stack(new_v, axis=0),
+        "attn_k_pages": kp,
+        "attn_v_pages": vp,
     }

@@ -5,6 +5,8 @@
   sliding window + logit softcap, page-table padding (max_blocks not a
   multiple of the page chunk), parked rows, in-contract unmapped tables
   (admission-tick shapes), and bfloat16 pools with BITWISE scatter parity;
+  on a stacked (L, NB+1, ...) pool addressed by layer, the same output and
+  rows as the single-layer call on that layer, other layers untouched;
 * dispatch discipline: CPU default routes to the gather fallback, forcing
   the kernel routes fused, an over-budget block (no (page_chunk,
   head_block) fits VMEM) falls back to gather — every decision recorded in
@@ -70,6 +72,12 @@ _CASES = {
                         positions=[5, 0, 17], tables=_seq_tables,
                         dtype=jnp.bfloat16),
 }
+# the stacked (L, NB+1, ...) pool a layer loop carries, addressed by layer
+for _layer in (0, 2):
+    _CASES[f"decode-stacked-l{_layer}"] = dict(
+        _CASES["decode-global"], layers=3, layer=_layer)
+    _CASES[f"verify-stacked-l{_layer}"] = dict(
+        _CASES["verify-ragged-parked"], t=3, layers=3, layer=_layer)
 
 
 @pytest.mark.parametrize("case", sorted(_CASES))
@@ -78,24 +86,42 @@ def test_kernel_matches_gather(case):
     dtype = c.pop("dtype", jnp.float32)
     tables, positions, pc, bh = (c.pop("tables"), c.pop("positions"),
                                  c.pop("pc"), c.pop("bh"))
+    layers, layer = c.pop("layers", None), c.pop("layer", None)
     b, t, hkv, group, dh = c["b"], c["t"], c["hkv"], c["group"], c["dh"]
     bs, mb, nb = c["bs"], c["mb"], c["nb"]
     r = jax.random.PRNGKey(0)
     q = jax.random.normal(r, (b, t, hkv * group, dh), dtype)
     knew = jax.random.normal(jax.random.fold_in(r, 1), (b, t, hkv, dh), dtype)
     vnew = jax.random.normal(jax.random.fold_in(r, 2), (b, t, hkv, dh), dtype)
-    kp = jax.random.normal(jax.random.fold_in(r, 3), (nb + 1, bs, hkv, dh),
-                           dtype)
-    vp = jax.random.normal(jax.random.fold_in(r, 4), (nb + 1, bs, hkv, dh),
-                           dtype)
+    pool = (nb + 1, bs, hkv, dh) if layer is None else \
+        (layers, nb + 1, bs, hkv, dh)
+    kp = jax.random.normal(jax.random.fold_in(r, 3), pool, dtype)
+    vp = jax.random.normal(jax.random.fold_in(r, 4), pool, dtype)
     tbl = tables(b, mb, nb)
     pos = jnp.asarray(positions, jnp.int32)
     win = jnp.int32(c["window"])
-    ro, rk, rv = paged_attention_ref(q, knew, vnew, kp, vp, tbl, pos, win,
-                                     c["softcap"])
-    fo, fk, fv = jax.jit(lambda *a: paged_attn.paged_attention(
+    kernel = jax.jit(lambda *a: paged_attn.paged_attention(
         *a, softcap=c["softcap"], page_chunk=pc, head_block=bh,
-        interpret=True))(q, knew, vnew, kp, vp, tbl, pos, win)
+        interpret=True))
+    if layer is None:
+        fo, fk, fv = kernel(q, knew, vnew, kp, vp, tbl, pos, win)
+        lk, lv = kp, vp
+    else:
+        fo, ak, av = kernel(q, knew, vnew, kp, vp, tbl, pos, win,
+                            jnp.int32(layer))
+        lk, lv = kp[layer], vp[layer]
+        # the single-layer call on pool[layer] writes the same rows, bit
+        # for bit, and every other layer's pages are untouched
+        so, sk, sv = kernel(q, knew, vnew, lk, lv, tbl, pos, win)
+        assert np.array_equal(np.asarray(fo), np.asarray(so))
+        assert np.array_equal(np.asarray(ak[layer]), np.asarray(sk))
+        assert np.array_equal(np.asarray(av[layer]), np.asarray(sv))
+        others = [i for i in range(layers) if i != layer]
+        assert np.array_equal(np.asarray(ak)[others], np.asarray(kp)[others])
+        assert np.array_equal(np.asarray(av)[others], np.asarray(vp)[others])
+        fk, fv = ak[layer], av[layer]
+    ro, rk, rv = paged_attention_ref(q, knew, vnew, lk, lv, tbl, pos, win,
+                                     c["softcap"])
     live = np.asarray(pos) < mb * bs
     tol = dict(atol=2e-5, rtol=2e-5) if dtype == jnp.float32 else \
         dict(atol=3e-2, rtol=3e-2)

@@ -13,18 +13,23 @@ file.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.dist import steps
 from repro.kernels import acdc_bwd
 from repro.kernels import acdc_factored
 from repro.kernels import acdc_cascade_bwd as cascade_bwd
 from repro.kernels import acdc_cascade_fused as cascade_fused
 from repro.kernels import paged_attn
+from repro.kernels import ops
 from repro.kernels import scaled_matmul
+from repro.models import get_model
+from repro.models.common import ModelConfig
 
 # Qwen3-1.7B serving shapes: 8 KV heads of 128 in groups of 2, 16-token
 # pages, 4 slots of 1024 + 32 + 1 positions
@@ -80,6 +85,71 @@ def test_paged_attention_compiles(spec, t):
         spec((SLOTS, MAX_BLOCKS), jnp.int32), spec((SLOTS,), jnp.int32),
         spec((), jnp.int32))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+#: the served head shapes over a few small layers: the pool's share of
+#: the program is what the paged-step guard reads, not the widths
+PAGED_CFG = ModelConfig(n_layers=3, d_model=256, n_heads=HKV * GROUP,
+                        n_kv_heads=HKV, head_dim=DH, d_ff=512,
+                        vocab_size=512, qk_norm=True)
+SPEC_K = 3
+#: one HLO instruction's result: ``= bf16[3,537,16,8,128]{...} copy(``
+_HLO_RESULT = re.compile(
+    r"= \w+\[([\d,]*)\]\S* (copy|dynamic-slice|dynamic-update-slice)\(")
+
+
+def _paged_step(spec, kind, n_blocks):
+    """The engine's paged decode or verify program over a pool of
+    ``n_blocks`` pages, compiled with the pool donated as the engine
+    donates it: ``(compiled, pool bytes, one layer's pool elements)``."""
+    cfg, model = PAGED_CFG, get_model(PAGED_CFG)
+    on_chip = lambda tree: jax.tree.map(  # noqa: E731
+        lambda s: spec(s.shape, s.dtype), tree)
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), cfg)))
+    cache = on_chip(jax.eval_shape(
+        lambda: model.init_cache_paged(cfg, SLOTS, n_blocks, BS)))
+    key = on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    i32 = lambda *shape: spec(shape, jnp.int32)  # noqa: E731
+    tables = i32(SLOTS, MAX_BLOCKS)
+    if kind == "decode":
+        fn = steps.make_serve_step(model, cfg, paged=True)
+        args = (i32(SLOTS), i32(SLOTS), tables, key)
+    else:
+        fn = steps.make_verify_step(model, cfg, paged=True,
+                                    park=MAX_BLOCKS * BS)
+        args = (i32(SLOTS, SPEC_K + 1), i32(SLOTS, SPEC_K),
+                spec((SLOTS, SPEC_K, cfg.vocab_size), jnp.float32),
+                i32(SLOTS), tables, key)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    pool = cache["k_pages"]
+    pool_bytes = 2 * pool.size * pool.dtype.itemsize
+    return compiled, pool_bytes, pool.size // pool.shape[0]
+
+
+@pytest.mark.parametrize("kind", ["decode", "verify"])
+def test_paged_step_updates_pool_in_place(spec, monkeypatch, kind):
+    """The paged decode and verify programs write only the new K/V rows
+    into the donated pool: their temporaries do not grow with the pool,
+    and no instruction copies, slices out or stacks back a layer's pool
+    (or more)."""
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    small, small_bytes, _ = _paged_step(spec, kind, SLOTS * MAX_BLOCKS)
+    big, big_bytes, layer_elems = _paged_step(spec, kind,
+                                              2 * SLOTS * MAX_BLOCKS)
+    temp = lambda c: c.memory_analysis().temp_size_in_bytes  # noqa: E731
+    assert temp(big) - temp(small) < 0.1 * (big_bytes - small_bytes)
+    text = big.as_text()
+    assert "tpu_custom_call" in text
+    pool_sized = []
+    for dims, op in _HLO_RESULT.findall(text):
+        elems = 1
+        for d in filter(None, dims.split(",")):
+            elems *= int(d)
+        if elems >= layer_elems:
+            pool_sized.append(f"{op}[{dims}]")
+    assert not pool_sized, pool_sized
 
 
 @pytest.mark.parametrize("rows", [4, 1024], ids=["decode", "prefill"])
